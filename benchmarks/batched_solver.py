@@ -123,7 +123,9 @@ SELL_BYTES_REDUCTION_MIN = 0.40
 
 #: Host device counts for the lane-sharded rows; each runs in a child
 #: interpreter with XLA_FLAGS forcing the split (the parent session
-#: stays single-device — same rule as tests/conftest.py).
+#: stays single-device — same rule as tests/conftest.py).  Only a
+#: CPU-backed parent spawns them: a parent on a chip holds the chip,
+#: and a child that needs it would fail or hang.
 SHARD_DEVICES = (1, 8)
 
 
@@ -372,7 +374,12 @@ def run(repeat_suite: int = 1, smoke: bool = False,
         assert r.iterations == p.iterations, "sharded/spec parity"
         assert np.array_equal(np.asarray(r.x), np.asarray(p.x)), \
             "lane-sharded run not bit-identical to unsharded VM"
-    for d in SHARD_DEVICES:
+    backend = jax.default_backend()
+    if backend != "cpu":
+        print(f"# sharded_vm rows not run: they spawn forced-CPU child "
+              f"interpreters, which this process may not do while it "
+              f"holds the {backend} device")
+    for d in SHARD_DEVICES if backend == "cpu" else ():
         info = _sharded_row_times(d, smoke, steps_per_sync, kw["maxiter"])
         t = info["time_s"]
         rows.append({"mode": f"sharded_vm_d{info['devices']}",

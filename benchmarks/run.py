@@ -51,6 +51,8 @@ def main(argv=None):
 
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compilation cache: {enable_compile_cache()}")
 
     from benchmarks import (batched_solver, engine_health,
                             fig9_residual_traces, roofline_table,

@@ -33,16 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                   # moved to jax.shard_map upstream
-    _shard_map = jax.shard_map
-except AttributeError:                 # pre-move JAX: the experimental
-    from functools import partial as _partial  # shard_map has no while_loop
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    # replication rule — disable the check (the solver psums every scalar
-    # the predicate reads, so replication is correct by construction)
-    _shard_map = _partial(_exp_shard_map, check_rep=False)
-
 from repro.core.operators import bell_spmv_jnp
 from repro.core.precision import PrecisionScheme, get_scheme
 from repro.sparse.partition import PartitionedMatrix, partition_rows
@@ -212,7 +202,7 @@ def make_dist_solver(a, mesh: Mesh, *, scheme="mixed_v3",
     kern = solve_pipe if method == "pipelined" else solve_vsr
     shard_in = (shard_spec,) * 4
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         kern, mesh=mesh,
         in_specs=(shard_in, vec_spec, vec_spec, vec_spec),
         out_specs=(vec_spec, rep, rep))

@@ -9,6 +9,8 @@ The TPU spelling of the same idea: Phase I accumulates an ``[8, LANES]``
 VMEM tile of partial sums — every VPU lane owns one partial, so the serial
 FP-add dependence is broken exactly as the delay buffer breaks it — and
 Phase II is a log-depth tree reduction of the tile on the final grid step.
+Mosaic stores no scalar to VMEM, so each result leaves the kernel
+broadcast over one ``(8, 128)`` tile of the output (:func:`_emit`).
 
 ``dot3`` fuses the three reductions of pipelined CG (γ = r·u, δ = w·u,
 ‖r‖²) into ONE sweep: r, u, w stream through VMEM once and three
@@ -24,12 +26,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 __all__ = ["dot_pallas", "dot3_pallas", "DOT_BLOCK"]
 
 #: rows × lanes of one grid-step tile (8 sublanes × 512 lanes of fp32).
 DOT_BLOCK = (8, 512)
+
+#: one vreg of fp32 — the output tile each scalar result is broadcast over.
+OUT_TILE = (8, 128)
 
 
 def _pad2d(v: jax.Array, dtype) -> jax.Array:
@@ -40,6 +43,18 @@ def _pad2d(v: jax.Array, dtype) -> jax.Array:
     nb = max(1, -(-n // chunk))
     vp = jnp.zeros(nb * chunk, dtype).at[:n].set(v.astype(dtype))
     return vp.reshape(nb, rows, lanes)
+
+
+def _emit(o_ref, k: int, value) -> None:
+    """Write scalar result ``k`` as a whole ``OUT_TILE`` of ``o_ref``."""
+    o_ref[k] = jnp.broadcast_to(value, OUT_TILE).astype(o_ref.dtype)
+
+
+def _scalars_out(k: int, dtype):
+    """Block spec and shape of ``k`` scalar results, one ``OUT_TILE``
+    each; the caller reads them at ``[:, 0, 0]``."""
+    return (pl.BlockSpec((k,) + OUT_TILE, lambda i: (0, 0, 0)),
+            jax.ShapeDtypeStruct((k,) + OUT_TILE, dtype))
 
 
 def _dot_kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -53,7 +68,7 @@ def _dot_kernel(a_ref, b_ref, o_ref, acc_ref):
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _reduce():                               # Phase II: tree reduce
-        o_ref[0, 0] = jnp.sum(acc_ref[...])
+        _emit(o_ref, 0, jnp.sum(acc_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("acc_dtype", "interpret"))
@@ -64,19 +79,21 @@ def dot_pallas(a: jax.Array, b: jax.Array, *, acc_dtype=jnp.float32,
     ap = _pad2d(a, acc_dtype)
     bp = _pad2d(b, acc_dtype)
     nb = ap.shape[0]
+    out_spec, out_shape = _scalars_out(1, acc_dtype)
     out = pl.pallas_call(
         _dot_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0)),
                   pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), acc_dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rows, lanes), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="dot",
     )(ap, bp)
-    return out[0, 0]
+    return out[0, 0, 0]
 
 
 def _dot3_kernel(r_ref, u_ref, w_ref, o_ref, accru_ref, accwu_ref, accrr_ref):
@@ -97,9 +114,9 @@ def _dot3_kernel(r_ref, u_ref, w_ref, o_ref, accru_ref, accwu_ref, accrr_ref):
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _reduce():
-        o_ref[0, 0] = jnp.sum(accru_ref[...])
-        o_ref[0, 1] = jnp.sum(accwu_ref[...])
-        o_ref[0, 2] = jnp.sum(accrr_ref[...])
+        _emit(o_ref, 0, jnp.sum(accru_ref[...]))
+        _emit(o_ref, 1, jnp.sum(accwu_ref[...]))
+        _emit(o_ref, 2, jnp.sum(accrr_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("acc_dtype", "interpret"))
@@ -111,15 +128,17 @@ def dot3_pallas(r: jax.Array, u: jax.Array, w: jax.Array, *,
     up = _pad2d(u, acc_dtype)
     wp = _pad2d(w, acc_dtype)
     nb = rp.shape[0]
+    out_spec, out_shape = _scalars_out(3, acc_dtype)
     out = pl.pallas_call(
         _dot3_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))] * 3,
-        out_specs=pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 3), acc_dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rows, lanes), acc_dtype)] * 3,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="dot3",
     )(rp, up, wp)
-    return out[0]
+    return out[:, 0, 0]

@@ -29,9 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
-from repro.kernels.dot import DOT_BLOCK, _pad2d
+from repro.kernels.dot import DOT_BLOCK, _emit, _pad2d, _scalars_out
 
 __all__ = ["phase2_pallas", "phase3_pallas"]
 
@@ -54,8 +52,8 @@ def _phase2_kernel(alpha_ref, r_ref, ap_ref, m_ref, rnew_ref, s_ref,
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _reduce():
-        s_ref[0, 0] = jnp.sum(accrr_ref[...])
-        s_ref[0, 1] = jnp.sum(accrz_ref[...])
+        _emit(s_ref, 0, jnp.sum(accrr_ref[...]))
+        _emit(s_ref, 1, jnp.sum(accrz_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -73,6 +71,7 @@ def phase2_pallas(alpha: jax.Array, r: jax.Array, ap: jax.Array,
     mp = jnp.ones(nb * chunk, dt).at[:n].set(diag.astype(dt)).reshape(
         nb, rows, lanes)
     a2 = jnp.asarray(alpha, dt).reshape(1, 1)
+    s_spec, s_shape = _scalars_out(2, dt)
 
     r_new, s = pl.pallas_call(
         _phase2_kernel,
@@ -82,15 +81,15 @@ def phase2_pallas(alpha: jax.Array, r: jax.Array, ap: jax.Array,
                   pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0)),
                   pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
         out_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, rows, lanes), dt),
-                   jax.ShapeDtypeStruct((1, 2), dt)],
+                   s_spec],
+        out_shape=[jax.ShapeDtypeStruct((nb, rows, lanes), dt), s_shape],
         scratch_shapes=[pltpu.VMEM((rows, lanes), dt)] * 2,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="phase2",
     )(a2, rp, app, mp)
-    return r_new.reshape(-1)[:n], s[0]
+    return r_new.reshape(-1)[:n], s[:, 0, 0]
 
 
 def _phase3_kernel(ab_ref, rnew_ref, m_ref, p_ref, x_ref, pnew_ref, xnew_ref):
@@ -132,8 +131,9 @@ def phase3_pallas(alpha: jax.Array, beta: jax.Array, r_new: jax.Array,
                    pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((nb, rows, lanes), dt),
                    jax.ShapeDtypeStruct((nb, rows, lanes), dt)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="phase3",
     )(ab, rp, mp, pp, xp)
     return p_new.reshape(-1)[:n], x_new.reshape(-1)[:n]

@@ -8,8 +8,10 @@
   JPCG loop body runs as three Pallas kernels per iteration — the paper's
   three phases, one kernel each.
 
-``interpret`` defaults to "not on TPU": kernels execute via the Pallas
-interpreter on CPU (correctness) and lower to Mosaic on TPU (performance).
+``interpret=None`` resolves through :func:`default_interpret`: the Pallas
+interpreter on the CPU backend only (the test suite), Mosaic everywhere
+else.  Nothing catches a Mosaic error and retries interpreted or on the
+XLA matvec: a kernel that does not compile for the device fails there.
 """
 from __future__ import annotations
 
@@ -31,8 +33,8 @@ __all__ = ["PallasEllOperator", "ell_operator_pallas", "bell_operator_pallas",
 
 
 def default_interpret() -> bool:
-    """Interpret unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Interpret on the CPU backend only; compile for any device."""
+    return jax.default_backend() == "cpu"
 
 
 @dataclasses.dataclass(frozen=True)

@@ -95,7 +95,7 @@ from repro.core.precision import get_scheme
 from repro.core.vm import BatchedVMState, make_vm_stepper
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.ellpack import csr_to_ellpack
-from repro.core.shard import mesh_shards
+from repro.core.shard import mesh_shards, place_lanes, place_vm_state
 from repro.sparse.stacking import (SELL_SLICE_ROWS, _sell_groups, bucket_up,
                                    choose_layout, csr_rowell, index_dtype,
                                    lane_bucket_up, pad_ellpack,
@@ -130,6 +130,14 @@ class SolverEngineConfig:
     mesh: Optional[object] = None     # jax.sharding.Mesh over the lane
     #                                   axis (repro.core.shard.lane_mesh);
     #                                   None = single-device pools
+
+
+@partial(jax.jit, donate_argnums=0)
+def _set_lane(arr, s, lane):
+    """``arr.at[s].set(lane)`` in place: the slot-stacked operand is
+    donated, so admission never holds two copies of it (at chip sizes
+    one copy of a pallas pool is several GB)."""
+    return arr.at[s].set(lane.astype(arr.dtype))
 
 
 @partial(jax.jit, static_argnames=("scheme",))
@@ -367,9 +375,8 @@ class _Pool:
                 st1 = stack_sell([a], n_pad=n_pad, widths=self.sell_widths,
                                  scheme=self.scheme)
                 lanes = (st1.cols[0], st1.vals[0], st1.iperm[0])
-                self.mat = tuple(
-                    arr.at[s].set(jnp.asarray(lane).astype(arr.dtype))
-                    for arr, lane in zip(self.mat, lanes))
+                self.mat = tuple(_set_lane(arr, s, jnp.asarray(lane))
+                                 for arr, lane in zip(self.mat, lanes))
         else:
             if cfg.backend == "xla":
                 cols_l, vals_l = csr_rowell(a)
@@ -400,9 +407,8 @@ class _Pool:
                 m = pad_ellpack(m, n_row_blocks=B, n_slabs=T, ell=L)
                 lanes = (m.tile_cols, m.vals, m.local_cols)
             self.csr_of_slot[s] = a
-            self.mat = tuple(
-                arr.at[s].set(jnp.asarray(lane).astype(arr.dtype))
-                for arr, lane in zip(self.mat, lanes))
+            self.mat = tuple(_set_lane(arr, s, jnp.asarray(lane))
+                             for arr, lane in zip(self.mat, lanes))
 
         vd = self.scheme.vector_dtype
         n = a.shape[0]
@@ -594,15 +600,18 @@ class _Pool:
                         if self.req_of_slot[s] is None]
                 sel_l += (o + free)[:t_per]
             sel = np.asarray(sel_l, np.int64)
+        # The gathers come back replicated under a mesh: lay the lanes out
+        # over it again, or every chip would hold the whole pool.
         sel_j = jnp.asarray(sel)
-        self.mat = tuple(arr[sel_j] for arr in self.mat)
+        self.mat = place_lanes(self.mesh,
+                               tuple(arr[sel_j] for arr in self.mat))
         st = self.state
-        self.state = st._replace(
+        self.state = place_vm_state(self.mesh, st._replace(
             it=st.it[sel_j], status=st.status[sel_j], mem=st.mem[:, sel_j],
             queues=st.queues[:, sel_j], sregs=st.sregs[:, sel_j],
-            active=st.active[sel_j], trace=st.trace[sel_j])
-        self.tol = self.tol[sel_j]
-        self.maxiter_vec = self.maxiter_vec[sel_j]
+            active=st.active[sel_j], trace=st.trace[sel_j]))
+        self.tol = place_lanes(self.mesh, self.tol[sel_j])
+        self.maxiter_vec = place_lanes(self.mesh, self.maxiter_vec[sel_j])
         self.req_of_slot = [self.req_of_slot[s] for s in sel]
         self.csr_of_slot = [self.csr_of_slot[s] for s in sel]
         self.n_of_slot = self.n_of_slot[sel]

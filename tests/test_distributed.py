@@ -11,6 +11,8 @@ import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -24,6 +26,7 @@ def _run(body: str, devices: int = 8) -> dict:
         jax.config.update("jax_enable_x64", True)
         import numpy as np
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         """)
     env = dict(os.environ, PYTHONPATH=SRC)
@@ -38,7 +41,6 @@ class TestShardingRules:
         """Rules give TP on output features, FSDP on inputs, EP on
         experts; uneven dims fall back to replication."""
         from repro.distributed.sharding import param_specs
-        from repro.launch.mesh import make_mesh  # noqa: F401
 
         class Leaf:
             def __init__(self, shape):
@@ -67,7 +69,7 @@ class TestShardingRules:
         import numpy as np
         if jax.device_count() != 1:
             pytest.skip("needs the default single-device session")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
 
         class Leaf:
             def __init__(self, shape):
@@ -85,7 +87,7 @@ class TestDistributedCG:
         out = _run(f"""
             from repro.sparse import poisson_2d, csr_to_dense
             from repro.distributed import make_dist_solver
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             A = poisson_2d(40)
             solver = make_dist_solver(A, mesh, scheme="mixed_v3",
                                       method="{method}", tol=1e-12,
@@ -106,7 +108,7 @@ class TestDistributedCG:
             from repro.sparse import poisson_2d
             from repro.distributed import make_dist_solver
             from repro.core.cg import jpcg_solve
-            mesh = jax.make_mesh((8,), ("rows",))
+            mesh = make_mesh((8,), ("rows",))
             A = poisson_2d(32)
             solver = make_dist_solver(A, mesh, scheme="mixed_v3",
                                       method="vsr", tol=1e-12,
@@ -130,7 +132,7 @@ class TestDistributedCG:
             from repro.sparse import poisson_2d
             from repro.distributed import make_dist_solver
             from repro.roofline.hlo_cost import _parse_computations
-            mesh = jax.make_mesh((8,), ("rows",))
+            mesh = make_mesh((8,), ("rows",))
             A = poisson_2d(16)
 
             def count(method):
@@ -174,7 +176,7 @@ class TestHaloExchange:
             from repro.sparse import poisson_2d, csr_to_dense
             from repro.distributed import make_dist_solver
             from repro.roofline.hlo_cost import walk_hlo
-            mesh = jax.make_mesh((8,), ("rows",))
+            mesh = make_mesh((8,), ("rows",))
             A = poisson_2d(64)
             d = csr_to_dense(A); b = np.ones(4096)
             res = {}
@@ -227,12 +229,12 @@ class TestElasticRemesh:
                               vocab=256, head_dim=16, dtype="float32",
                               remat=False)
             params = init_params(cfg, jax.random.PRNGKey(0))
-            mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+            mesh_a = make_mesh((4, 2), ("data", "model"))
             sh_a = named_shardings(param_specs(params, mesh_a), mesh_a)
             params_a = jax.tree_util.tree_map(jax.device_put, params, sh_a)
             ckpt.save("{tmp_path}", 1, params_a)
 
-            mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_b = make_mesh((2, 4), ("data", "model"))
             restored, _ = elastic_restore("{tmp_path}", params, mesh_b)
             ok = all(bool(jnp.allclose(a.astype(jnp.float32),
                                        b.astype(jnp.float32)))
@@ -262,7 +264,7 @@ class TestMeshTrainStep:
                               d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
                               vocab=256, head_dim=16, dtype="float32",
                               remat=False)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             params = init_params(cfg, jax.random.PRNGKey(0))
             pshape = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import hints
+from repro.launch.mesh import make_mesh
 
 
 def test_noop_without_context():
@@ -13,7 +14,7 @@ def test_noop_without_context():
 
 
 def test_resolution_single_device():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with hints.sharding_hints(mesh):
         assert hints.active_mesh() is mesh
         x = jnp.arange(8.0).reshape(2, 4)
@@ -23,7 +24,7 @@ def test_resolution_single_device():
 
 
 def test_missing_axes_dropped():
-    mesh = jax.make_mesh((1,), ("rows",))   # no data/model axes
+    mesh = make_mesh((1,), ("rows",))   # no data/model axes
     with hints.sharding_hints(mesh):
         x = jnp.ones((4, 4))
         y = hints.hint(x, hints.DATA, hints.MODEL)
@@ -31,7 +32,7 @@ def test_missing_axes_dropped():
 
 
 def test_context_nesting_restores():
-    mesh = jax.make_mesh((1,), ("rows",))
+    mesh = make_mesh((1,), ("rows",))
     with hints.sharding_hints(mesh):
         with hints.sharding_hints(None):
             assert hints.active_mesh() is None
@@ -39,7 +40,7 @@ def test_context_nesting_restores():
 
 
 def test_hint_inside_jit_traces():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def f(x):
         return hints.hint(x, hints.DATA, None) * 2.0

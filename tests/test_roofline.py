@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo_bytes import collective_bytes, parse_collectives
 from repro.roofline.hlo_cost import walk_hlo
 from repro.roofline.model import (V5E, model_flops_train, roofline_terms)
@@ -90,7 +91,7 @@ ENTRY %main (p: f32[16,16]) -> f32[16,16] {
         from jax.sharding import NamedSharding, PartitionSpec as P
         if jax.device_count() < 2:
             pytest.skip("single-device session")
-        mesh = jax.make_mesh((jax.device_count(),), ("d",))
+        mesh = make_mesh((jax.device_count(),), ("d",))
         s = NamedSharding(mesh, P(None, "d"))
         c = _compile(lambda a, b: a @ b,
                      jax.ShapeDtypeStruct((32, 64), jnp.float32),
