@@ -1,8 +1,6 @@
-"""Shared benchmark utilities: suite, timing, profiling, CSV + JSON
-output."""
+"""Shared benchmark utilities: timing, CSV + JSON output."""
 from __future__ import annotations
 
-import contextlib
 import json
 import pathlib
 import platform
@@ -27,27 +25,6 @@ def time_solve(fn: Callable, *args, repeats: int = 3, **kw):
         best.append(time.perf_counter() - t0)
     best.sort()
     return out, best[len(best) // 2]
-
-
-@contextlib.contextmanager
-def profile_trace(dirpath: Optional[str]):
-    """Opt-in ``jax.profiler`` trace around a benchmark section.
-
-    ``dirpath`` falsy → no-op (the default: profiling costs time and
-    disk, so it never runs unless asked for).  Otherwise the section
-    executes under ``jax.profiler.start_trace(dirpath)`` and the trace
-    lands in ``dirpath`` for TensorBoard (``tensorboard --logdir``) or
-    Perfetto (``ui.perfetto.dev``, load the ``*.trace.json.gz``).
-    """
-    if not dirpath:
-        yield
-        return
-    jax.profiler.start_trace(str(dirpath))
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        print(f"# profiler trace written under {dirpath}")
 
 
 def emit(rows, header):

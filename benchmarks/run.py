@@ -1,7 +1,6 @@
 """Benchmark driver — one section per paper table/figure.
 
-``python -m benchmarks.run [--tier small|large|all] [--smoke]
-[--profile DIR]``
+``python -m benchmarks.run [--tier small|large|all] [--smoke]``
 
 Every section that returns rows is also persisted as machine-readable
 ``BENCH_<name>.json`` at the repo root (see
@@ -25,11 +24,6 @@ deliberately-singular lane must exit ``BREAKDOWN_INDEFINITE`` in fewer
 than maxiter iterations, and the engine's ``bytes_streamed_est`` metric
 must agree with the packed-array accounting within
 ``benchmarks.engine_health.BYTES_REL_ERR_MAX`` (1%).
-
-``--profile DIR`` wraps every section in a ``jax.profiler`` trace
-(``benchmarks.common.profile_trace``) written under ``DIR/<section>``
-for TensorBoard/Perfetto; profiling is strictly opt-in because it
-costs time and disk.
 """
 from __future__ import annotations
 
@@ -43,10 +37,6 @@ def main(argv=None):
                     choices=["small", "large", "all"])
     ap.add_argument("--smoke", action="store_true",
                     help="fast subset for CI; still emits BENCH_*.json")
-    ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="write a jax.profiler trace per section under "
-                         "DIR/<section> (TensorBoard/Perfetto); off by "
-                         "default")
     args = ap.parse_args(argv)
 
     import jax
@@ -58,7 +48,7 @@ def main(argv=None):
                             fig9_residual_traces, roofline_table,
                             spmv_kernel, tab4_solver_time, tab5_throughput,
                             tab7_iterations, vsr_access_counts)
-    from benchmarks.common import profile_trace, write_bench_json
+    from benchmarks.common import write_bench_json
 
     sections = [
         ("vsr_access_counts",
@@ -91,9 +81,7 @@ def main(argv=None):
     for name, title, fn, kw in sections:
         print(f"\n=== {title} ===")
         t0 = time.time()
-        with profile_trace(f"{args.profile}/{name}" if args.profile
-                           else None):
-            rows = fn(**kw)
+        rows = fn(**kw)
         elapsed = time.time() - t0
         if rows is not None:
             meta = {"tier": args.tier, "smoke": args.smoke,

@@ -68,6 +68,7 @@ see ``tests/README.md``.
 """
 from __future__ import annotations
 
+import itertools
 from functools import partial
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -78,13 +79,14 @@ import numpy as np
 from repro.core.cg import CGResult
 from repro.core.metrics import (advance_status, finalize_status,
                                 initial_status, is_breakdown,
-                                solver_metrics, status_name, tick_health)
+                                solver_metrics, span, status_name,
+                                tick_health)
 from repro.core.phases import vsr_iteration
 from repro.core.precision import PrecisionScheme, get_scheme
 from repro.sparse.csr import CSRMatrix, csr_from_coo
 from repro.sparse.ellpack import csr_to_ellpack
-from repro.sparse.stacking import (StackedEllpack, choose_layout,
-                                   stack_ellpack, stack_rowell, stack_sell)
+from repro.sparse.stacking import (choose_layout, stack_ellpack,
+                                   stack_rowell, stack_sell)
 
 __all__ = ["BatchedCGState", "jpcg_solve_batched", "batched_matvec_flat",
            "batched_matvec_rowell", "batched_matvec_sell",
@@ -223,13 +225,15 @@ def batched_matvec_rowell(cols, vals, x, *,
     :func:`batched_matvec_flat`).  Casts follow the scheme contract
     (matrix dtype on ``vals`` packed at rest by the stacker, ``spmv_in``
     on the gathered x, accumulate at ``spmv_acc``, result at
-    ``vector``).
+    ``vector``).  Its device operations carry the scope
+    ``m1_xla_rowell``.
     """
-    acc = scheme.spmv_acc_dtype
-    x_in = x.astype(scheme.spmv_in_dtype)
-    xg = jax.vmap(lambda xv, c: xv[c])(x_in, cols)        # [G, W, n_pad]
-    y = tree_sum(rounded_products(vals, xg, acc), axis=1)
-    return y.astype(scheme.vector_dtype)
+    with jax.named_scope("m1_xla_rowell"):
+        acc = scheme.spmv_acc_dtype
+        x_in = x.astype(scheme.spmv_in_dtype)
+        xg = jax.vmap(lambda xv, c: xv[c])(x_in, cols)    # [G, W, n_pad]
+        y = tree_sum(rounded_products(vals, xg, acc), axis=1)
+        return y.astype(scheme.vector_dtype)
 
 
 def batched_matvec_sell(cols, vals, iperm, x, *, groups,
@@ -244,23 +248,25 @@ def batched_matvec_sell(cols, vals, iperm, x, *, groups,
     matches row-ELL and the tree bracketing is suffix-stable, the result
     is bit-identical to :func:`batched_matvec_rowell` on the same
     matrix — the layout choice is invisible to the solver trajectory.
+    Its device operations carry the scope ``m1_xla_sell``.
     """
-    acc = scheme.spmv_acc_dtype
-    x_in = x.astype(scheme.spmv_in_dtype)
-    G = x.shape[0]
-    parts, off = [], 0
-    for rows, w in groups:
-        if w == 0:
-            parts.append(jnp.zeros((G, rows), acc))
-            continue
-        c = cols[:, off:off + rows * w].reshape(G, w, rows)
-        v = vals[:, off:off + rows * w].reshape(G, w, rows)
-        xg = jax.vmap(lambda xv, cc: xv[cc])(x_in, c)     # [G, w, rows]
-        parts.append(tree_sum(rounded_products(v, xg, acc), axis=1))
-        off += rows * w
-    y_sorted = jnp.concatenate(parts, axis=1)             # [G, n_pad]
-    y = jnp.take_along_axis(y_sorted, iperm, axis=1)
-    return y.astype(scheme.vector_dtype)
+    with jax.named_scope("m1_xla_sell"):
+        acc = scheme.spmv_acc_dtype
+        x_in = x.astype(scheme.spmv_in_dtype)
+        G = x.shape[0]
+        parts, off = [], 0
+        for rows, w in groups:
+            if w == 0:
+                parts.append(jnp.zeros((G, rows), acc))
+                continue
+            c = cols[:, off:off + rows * w].reshape(G, w, rows)
+            v = vals[:, off:off + rows * w].reshape(G, w, rows)
+            xg = jax.vmap(lambda xv, cc: xv[cc])(x_in, c)  # [G, w, rows]
+            parts.append(tree_sum(rounded_products(v, xg, acc), axis=1))
+            off += rows * w
+        y_sorted = jnp.concatenate(parts, axis=1)          # [G, n_pad]
+        y = jnp.take_along_axis(y_sorted, iperm, axis=1)
+        return y.astype(scheme.vector_dtype)
 
 
 def batched_matvec_ellpack(tile_cols, vals, local_cols, x, *,
@@ -426,6 +432,8 @@ def _run_chunked(cond, tick, st, *, steps: int, with_trace: bool,
 # ------------------------------------------------------------ compile cache
 _CACHE: dict = {}
 _CACHE_STATS = {"hits": 0, "misses": 0}
+#: ids of batch calls: the ``rid`` of each call's spans
+_CALL_IDS = itertools.count()
 
 
 def batch_cache_info() -> dict:
@@ -661,19 +669,106 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
         raise RuntimeError(
             f"scheme {scheme.name!r} needs fp64 vectors: enable x64 first "
             "or use a TPU-tier scheme (tpu_v3, ...).")
-    csrs = [_as_csr(a) for a in problems]
-    G = len(csrs)
-    if G == 0:
-        return []
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
+    rid = next(_CALL_IDS)
+    with span("batch.solve", rid=rid):
+        with span("batch.prepare", rid=rid):
+            bag = _prepare(problems, bs, x0s, tol, scheme=scheme,
+                           backend=backend, layout=layout, bucket=bucket,
+                           block_rows=block_rows, col_tile=col_tile,
+                           mesh=mesh, rid=rid)
+        if bag is None:
+            return []
+        key, make, args, method = _runner(
+            bag, engine=engine, policy=policy, program=program,
+            specialize=specialize, scheme=scheme, backend=backend,
+            maxiter=maxiter, with_trace=with_trace, block_rows=block_rows,
+            col_tile=col_tile, steps_per_sync=steps_per_sync,
+            donate=donate, detect=detect, interpret=interpret, mesh=mesh)
+        with span("batch.launch", rid=rid):
+            st = _cached(key, make)(*args)
+            if engine == "vm":
+                from repro.core.isa import BUF, SREG
+                xs = st.mem[BUF["x"]]
+                rrs_dev, trace_dev = st.sregs[SREG["rr"]], st.trace
+            else:
+                xs, rrs_dev, trace_dev = st.x, st.rr, st.trace
+        with span("batch.wait", rid=rid):
+            its = np.asarray(st.it)
+        with span("batch.results", rid=rid):
+            return _results(bag, its, np.asarray(rrs_dev),
+                            np.asarray(st.status), xs, trace_dev,
+                            scheme=scheme, method=method,
+                            with_trace=with_trace, with_status=with_status)
 
+
+class _Bag(NamedTuple):
+    """A batch call's operands on the device, and the shapes its results
+    and its executable's key need."""
+
+    G: int                  # lanes, shard padding included
+    G_real: int             # lanes of the caller's problems
+    ns: List[int]           # each lane's rows
+    layout: str
+    groups: Optional[tuple]
+    n_col_tiles: Optional[int]
+    bucket_dims: tuple
+    index_bytes: int
+    mat: tuple
+    diag: jax.Array
+    b: jax.Array
+    x0: jax.Array
+    tol: jax.Array
+
+
+def _pack(csrs, *, backend, layout, scheme, bucket, block_rows, col_tile):
+    """Stack the lanes' matrices on the host in ``layout``: returns the
+    stacked operand, its host arrays and its static shape signature."""
+    groups = n_col_tiles = None
+    if layout == "sell":
+        stacked = stack_sell(csrs, bucket=bucket, scheme=scheme)
+        host = (stacked.cols, stacked.vals, stacked.iperm)
+        groups = stacked.groups
+        # flat ints only: executable_key ravels the bucket dims
+        bucket_dims = (stacked.padded_rows,
+                       *(d for rw in groups for d in rw))
+        index_bytes = stacked.index_bytes
+    elif backend == "xla" and layout == "rowell":
+        stacked = stack_rowell(csrs, bucket=bucket, scheme=scheme)
+        host = (stacked.cols, stacked.vals)
+        bucket_dims = (stacked.padded_rows, stacked.width)
+        index_bytes = stacked.index_bytes
+    elif backend == "pallas" and layout == "ellpack":
+        stacked = stack_ellpack(
+            [csr_to_ellpack(a, block_rows=block_rows, col_tile=col_tile)
+             for a in csrs], bucket=bucket)
+        host = (stacked.tile_cols, stacked.vals, stacked.local_cols)
+        n_col_tiles = stacked.n_col_tiles
+        bucket_dims = stacked.vals.shape[1:]
+        index_bytes = int(stacked.local_cols.dtype.itemsize)
+    else:
+        raise ValueError(f"unsupported backend/layout combination "
+                         f"{backend!r}/{layout!r}")
+    return stacked, host, groups, n_col_tiles, bucket_dims, index_bytes
+
+
+def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
+             block_rows, col_tile, mesh, rid) -> Optional[_Bag]:
+    """Choose the layout, pack the lanes and put every operand on the
+    device (spans ``batch.layout``, ``batch.pack``, ``batch.put``);
+    ``None`` for an empty bag."""
+    csrs = [_as_csr(a) for a in problems]
+    G = len(csrs)
+    if G == 0:
+        return None
     if backend not in ("xla", "pallas"):
         raise ValueError(f"unknown backend {backend!r}")
     if layout in (None, "auto"):
-        layout = choose_layout(
-            csrs, default="rowell" if backend == "xla" else "ellpack")
+        with span("batch.layout", rid=rid):
+            layout = choose_layout(
+                csrs, default="rowell" if backend == "xla" else "ellpack")
     # Lane sharding: NamedSharding needs the lane axis divisible by the
     # shard count, so the bag is padded with inert identity lanes
     # (b = x0 = 0 -> rr = 0, converged at admission, dropped from the
@@ -685,43 +780,13 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
         G = pad_lanes(G, mesh)
         if G != G_real:
             csrs = csrs + [_as_csr(np.eye(1))] * (G - G_real)
-    groups = None
-    n_col_tiles = None
-    if layout == "sell":
-        stacked = stack_sell(csrs, bucket=bucket, scheme=scheme)
-        mat = (jnp.asarray(stacked.cols), jnp.asarray(stacked.vals),
-               jnp.asarray(stacked.iperm))
-        groups = stacked.groups
-        # flat ints only: executable_key ravels the bucket dims
-        bucket_dims = (stacked.padded_rows,
-                       *(d for rw in groups for d in rw))
-        index_bytes = stacked.index_bytes
-    elif backend == "xla" and layout == "rowell":
-        stacked = stack_rowell(csrs, bucket=bucket, scheme=scheme)
-        mat = (jnp.asarray(stacked.cols), jnp.asarray(stacked.vals))
-        bucket_dims = (stacked.padded_rows, stacked.width)
-        index_bytes = stacked.index_bytes
-    elif backend == "pallas" and layout == "ellpack":
-        stacked_e: StackedEllpack = stack_ellpack(
-            [csr_to_ellpack(a, block_rows=block_rows, col_tile=col_tile)
-             for a in csrs], bucket=bucket)
-        mat = (jnp.asarray(stacked_e.tile_cols),
-               jnp.asarray(stacked_e.vals).astype(scheme.matrix_dtype),
-               jnp.asarray(stacked_e.local_cols))
-        stacked = stacked_e
-        n_col_tiles = stacked_e.n_col_tiles
-        bucket_dims = stacked_e.vals.shape[1:]
-        index_bytes = int(stacked_e.local_cols.dtype.itemsize)
-    else:
-        raise ValueError(f"unsupported backend/layout combination "
-                         f"{backend!r}/{layout!r}")
+    with span("batch.pack", rid=rid):
+        stacked, host, groups, n_col_tiles, bucket_dims, index_bytes = \
+            _pack(csrs, backend=backend, layout=layout, scheme=scheme,
+                  bucket=bucket, block_rows=block_rows, col_tile=col_tile)
 
-    vd = scheme.vector_dtype
     n_pad = stacked.padded_rows
     ns = [s[0] for s in stacked.shapes]
-    # Padded rows get a unit diagonal and zero rhs: their residual is
-    # identically zero, so they never influence rr or termination.
-    diag = _pad_stack([a.diagonal() for a in csrs], n_pad, 1.0, vd)
     bs = list(bs) if bs is not None else [np.ones(n) for n in ns[:G_real]]
     x0s = (list(x0s) if x0s is not None
            else [np.zeros(n) for n in ns[:G_real]])
@@ -738,85 +803,85 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
         # Shard-padding lanes: zero rhs/start on the identity dummy.
         bs = bs + [np.zeros(1)] * (G - G_real)
         x0s = x0s + [np.zeros(1)] * (G - G_real)
-    b = _pad_stack(bs, n_pad, 0.0, vd)
-    x0 = _pad_stack(x0s, n_pad, 0.0, vd)
-    if np.ndim(tol) == 0:
-        tol_vec = jnp.full(G, float(tol), vd)
-    else:
-        if len(tol) != G_real:
-            raise ValueError(
-                f"tol has {len(tol)} entries for {G_real} problems")
-        tol_vec = jnp.asarray(
-            np.concatenate([np.asarray(tol, np.float64),
-                            np.ones(G - G_real)]), vd)
+    if np.ndim(tol) != 0 and len(tol) != G_real:
+        raise ValueError(f"tol has {len(tol)} entries for {G_real} problems")
 
-    if engine == "vm":
-        # Specialized (default): the program is unrolled into the
-        # executable, so its bytes join the cache key (via program_token)
-        # — word-identical programs share one executable.  Generic
-        # fallback: the executable is keyed on the bucket — NOT on the
-        # program or policy; the program is a runtime operand (program
-        # *length* participates only through the operand's shape).
-        from repro.core.compile import canonical_program, executable_key
-        from repro.core.isa import BUF, SREG
-        from repro.core.vm import make_vm_runner
-        if program is None:
-            policy = "paper" if policy is None else policy
-            program = canonical_program(policy)
-            method = f"vm_batched[{policy}]"
+    vd = scheme.vector_dtype
+    with span("batch.put", rid=rid):
+        mat = tuple(jnp.asarray(h) for h in host)
+        if layout == "ellpack":
+            mat = (mat[0], mat[1].astype(scheme.matrix_dtype), mat[2])
+        # Padded rows get a unit diagonal and zero rhs: their residual is
+        # identically zero, so they never influence rr or termination.
+        diag = _pad_stack([a.diagonal() for a in csrs], n_pad, 1.0, vd)
+        b = _pad_stack(bs, n_pad, 0.0, vd)
+        x0 = _pad_stack(x0s, n_pad, 0.0, vd)
+        if np.ndim(tol) == 0:
+            tol_vec = jnp.full(G, float(tol), vd)
         else:
-            method = "vm_batched[custom]"
-        if not specialize:
-            method += "|generic"
-        prog_np = np.asarray(program, np.int32)
-        runner_kw = dict(
-            backend=backend, scheme=scheme, maxiter=maxiter,
-            with_trace=with_trace, layout=layout, groups=groups,
-            block_rows=block_rows, col_tile=col_tile,
-            n_col_tiles=n_col_tiles, steps_per_sync=steps_per_sync,
-            donate=donate, detect=detect, interpret=interpret, mesh=mesh)
-        key_kw = dict(
-            backend=backend, scheme=scheme.name, batch=G,
-            bucket=bucket_dims, layout=layout, index_bytes=index_bytes,
-            maxiter=maxiter, with_trace=with_trace,
-            steps_per_sync=steps_per_sync, donate=donate, detect=detect,
-            interpret=interpret, mesh=mesh)
-        if specialize:
-            key = executable_key("vm_solve_spec", program=prog_np,
-                                 **key_kw)
-            run = _cached(key, lambda: make_vm_runner(program=prog_np,
-                                                      **runner_kw))
-            st = run(mat, diag, b, x0, tol_vec)
-        else:
-            key = executable_key("vm_solve", **key_kw)
-            run = _cached(key, lambda: make_vm_runner(**runner_kw))
-            st = run(jnp.asarray(prog_np), mat, diag, b, x0, tol_vec)
-        xs = st.mem[BUF["x"]]
-        rrs_dev, trace_dev = st.sregs[SREG["rr"]], st.trace
-    elif engine == "phases":
-        from repro.core.compile import executable_key
-        key = executable_key(
-            "solve", backend=backend, scheme=scheme.name, batch=G,
-            bucket=bucket_dims, layout=layout, index_bytes=index_bytes,
-            maxiter=maxiter, with_trace=with_trace,
-            steps_per_sync=steps_per_sync, donate=donate, detect=detect,
-            interpret=interpret, mesh=mesh)
-        run = _cached(key, lambda: _make_runner(
-            backend=backend, scheme=scheme, maxiter=maxiter,
-            with_trace=with_trace, layout=layout, groups=groups,
-            block_rows=block_rows, col_tile=col_tile,
-            n_col_tiles=n_col_tiles, steps_per_sync=steps_per_sync,
-            donate=donate, detect=detect, interpret=interpret, mesh=mesh))
-        st = run(mat, diag, b, x0, tol_vec)
-        xs, rrs_dev, trace_dev = st.x, st.rr, st.trace
-        method = "vsr_batched"
-    else:
+            tol_vec = jnp.asarray(
+                np.concatenate([np.asarray(tol, np.float64),
+                                np.ones(G - G_real)]), vd)
+    return _Bag(G=G, G_real=G_real, ns=ns, layout=layout, groups=groups,
+                n_col_tiles=n_col_tiles, bucket_dims=bucket_dims,
+                index_bytes=index_bytes, mat=mat, diag=diag, b=b, x0=x0,
+                tol=tol_vec)
+
+
+def _runner(bag: _Bag, *, engine, policy, program, specialize, scheme,
+            backend, maxiter, with_trace, block_rows, col_tile,
+            steps_per_sync, donate, detect, interpret, mesh):
+    """``(cache key, make, args, method)``: the executable for the bag
+    (``make`` builds it on a cache miss) and the operands it takes."""
+    operands = (bag.mat, bag.diag, bag.b, bag.x0, bag.tol)
+    key_kw = dict(
+        backend=backend, scheme=scheme.name, batch=bag.G,
+        bucket=bag.bucket_dims, layout=bag.layout,
+        index_bytes=bag.index_bytes, maxiter=maxiter, with_trace=with_trace,
+        steps_per_sync=steps_per_sync, donate=donate, detect=detect,
+        interpret=interpret, mesh=mesh)
+    runner_kw = dict(
+        backend=backend, scheme=scheme, maxiter=maxiter,
+        with_trace=with_trace, layout=bag.layout, groups=bag.groups,
+        block_rows=block_rows, col_tile=col_tile,
+        n_col_tiles=bag.n_col_tiles, steps_per_sync=steps_per_sync,
+        donate=donate, detect=detect, interpret=interpret, mesh=mesh)
+    from repro.core.compile import executable_key
+    if engine == "phases":
+        key = executable_key("solve", **key_kw)
+        return (key, lambda: _make_runner(**runner_kw), operands,
+                "vsr_batched")
+    if engine != "vm":
         raise ValueError(f"unknown engine {engine!r}")
+    # Specialized (default): the program is unrolled into the
+    # executable, so its bytes join the cache key (via program_token)
+    # — word-identical programs share one executable.  Generic
+    # fallback: the executable is keyed on the bucket — NOT on the
+    # program or policy; the program is a runtime operand (program
+    # *length* participates only through the operand's shape).
+    from repro.core.compile import canonical_program
+    from repro.core.vm import make_vm_runner
+    if program is None:
+        policy = "paper" if policy is None else policy
+        program = canonical_program(policy)
+        method = f"vm_batched[{policy}]"
+    else:
+        method = "vm_batched[custom]"
+    prog_np = np.asarray(program, np.int32)
+    if specialize:
+        key = executable_key("vm_solve_spec", program=prog_np, **key_kw)
+        return (key, lambda: make_vm_runner(program=prog_np, **runner_kw),
+                operands, method)
+    key = executable_key("vm_solve", **key_kw)
+    return (key, lambda: make_vm_runner(**runner_kw),
+            (jnp.asarray(prog_np),) + operands, method + "|generic")
 
-    its = np.asarray(st.it)
-    rrs = np.asarray(rrs_dev)
-    tols = np.asarray(tol_vec)
-    statuses = np.asarray(st.status)
+
+def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, *, scheme,
+             method, with_trace, with_status) -> List[CGResult]:
+    """Feed the call's counters and build one ``CGResult`` per lane."""
+    G, G_real, mat = bag.G, bag.G_real, bag.mat
+    tols = np.asarray(bag.tol)
 
     # Observability (estimates, host-side): one SpMV per warm-up, per
     # committed iteration, and per discarded breakdown tick; streamed
@@ -824,7 +889,7 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
     # indices as packed — padding already included, so this IS
     # nonzero_stream_bytes x padding_ratio x nnz).
     m = solver_metrics()
-    if layout == "ellpack":
+    if bag.layout == "ellpack":
         lane_stream_bytes = (mat[1].nbytes + mat[2].nbytes) // G
     else:
         lane_stream_bytes = (mat[0].nbytes + mat[1].nbytes) // G
@@ -847,7 +912,7 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
     for g in range(G_real):
         trace = (np.asarray(trace_dev[g])[: its[g]] if with_trace else None)
         results.append(CGResult(
-            x=xs[g, : ns[g]], iterations=int(its[g]), rr=float(rrs[g]),
+            x=xs[g, : bag.ns[g]], iterations=int(its[g]), rr=float(rrs[g]),
             converged=bool(rrs[g] <= tols[g]), residual_trace=trace,
             scheme=scheme.name, method=method,
             status=status_name(int(statuses[g])) if with_status else None))

@@ -35,13 +35,30 @@ non-terminal value)::
 (snapshotable as a dict) used by :class:`repro.serve.SolverEngine`
 (engine-owned instance, ``SolverEngine.metrics()``) and by
 :func:`repro.core.batch.jpcg_solve_batched` (module-global instance,
-:func:`solver_metrics`), printed by ``benchmarks/run.py``.
+:func:`solver_metrics`).
+
+Span recorder: :func:`span` marks a host-side stretch of the solve path
+(``batch.pack``, ``engine.admit.warm``, ...) and :func:`record` keeps a
+finished interval after the fact (``engine.request``, from admission to
+harvest).  Recording is off by default, and then :func:`span` returns
+one shared no-op context: a flag test, no clock read, no allocation.
+Between :func:`start_spans` and :func:`stop_spans` each span also opens
+``jax.profiler.TraceAnnotation("repro." + name)``, so it lands in a
+profiler trace on the clock the device operations are on, and is kept
+in memory as a :class:`SpanRecord` on ``time.perf_counter_ns``.  No
+span synchronizes with the device: one that ends before the device
+finishes says so in its name (``launch``), and where the host blocks,
+the pull that already blocks ends the span (``wait``).  The recorder
+is process-wide and meant for one thread, the one driving the solver.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from collections import Counter
-from typing import Dict, Optional, Union
+from typing import Dict, List, NamedTuple, Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,7 +68,8 @@ __all__ = ["STATUS_RUNNING", "STATUS_CONVERGED", "STATUS_MAXITER",
            "is_breakdown", "is_breakdown_codes", "initial_status",
            "tick_health",
            "advance_status", "finalize_status", "Metrics",
-           "solver_metrics", "reset_solver_metrics"]
+           "solver_metrics", "reset_solver_metrics", "SpanRecord",
+           "span", "record", "start_spans", "stop_spans"]
 
 # ------------------------------------------------------------- status codes
 #: Lane still iterating (the only non-terminal status).
@@ -229,3 +247,77 @@ def solver_metrics() -> Metrics:
 
 def reset_solver_metrics() -> None:
     _GLOBAL.reset()
+
+
+# ------------------------------------------------------------------- spans
+class SpanRecord(NamedTuple):
+    """One finished span: ``perf_counter_ns`` bounds, the name of the
+    span open around it when it began (``None`` at top level or for a
+    :func:`record`), and the batch call's or request's id."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[str]
+    rid: Optional[int]
+
+
+#: finished spans while recording, else None (recording off)
+_RECORDS: Optional[List[SpanRecord]] = None
+#: spans open now, innermost last
+_OPEN: List["_Span"] = []
+_OFF = contextlib.nullcontext()
+
+
+class _Span:
+    """A span while recording; ``with`` yields it (``start_ns`` is set)."""
+
+    __slots__ = ("name", "rid", "parent", "start_ns", "_ann")
+
+    def __init__(self, name: str, rid: Optional[int]):
+        self.name, self.rid = name, rid
+
+    def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
+        self.parent = _OPEN[-1].name if _OPEN else None
+        _OPEN.append(self)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter_ns()
+        _OPEN.pop()
+        if _RECORDS is not None:
+            _RECORDS.append(SpanRecord(self.name, self.start_ns, end,
+                                       self.parent, self.rid))
+        self._ann.__exit__(*exc)
+
+
+def span(name: str, *, rid: Optional[int] = None):
+    """Context manager marking ``name`` on the host (see the module
+    docstring); yields the open span while recording, else ``None``."""
+    if _RECORDS is None:
+        return _OFF
+    return _Span(name, rid)
+
+
+def record(name: str, start_ns: int, end_ns: int,
+           rid: Optional[int] = None) -> None:
+    """Keep an interval measured elsewhere (in memory only, no parent)."""
+    if _RECORDS is not None:
+        _RECORDS.append(SpanRecord(name, start_ns, end_ns, None, rid))
+
+
+def start_spans() -> None:
+    """Start recording spans (dropping any records not yet handed back)."""
+    global _RECORDS
+    _RECORDS = []
+
+
+def stop_spans() -> List[SpanRecord]:
+    """Stop recording; returns the records since :func:`start_spans`
+    (empty if recording was off)."""
+    global _RECORDS
+    out, _RECORDS = _RECORDS or [], None
+    return out

@@ -346,7 +346,9 @@ def _run_specialized(plan: _ProgramPlan, matvec, mem: dict, queues: dict,
     arithmetic is word-for-word the generic executor's — same ops, same
     order, same dtypes — only the dispatch is resolved at trace time, so
     results are bit-identical to the generic path (and hence to the
-    phases oracle).
+    phases oracle).  The device operations of each module kind carry a
+    scope of its own (``vm_dot``, ``vm_axpy``, ``vm_div``, ``vm_ctrl``;
+    M1's is the matvec's), which changes their metadata only.
     """
     mem = dict(mem)
     queues = dict(queues)
@@ -363,25 +365,29 @@ def _run_specialized(plan: _ProgramPlan, matvec, mem: dict, queues: dict,
             mod, neg, qa, qb, qd, sr = w[1], w[2], w[4], w[5], w[6], w[7]
             kind = _BRANCH_OF_MOD[mod]
             a = queues[qa]
-            if kind == 0:                # M1: SpMV
+            if kind == 0:                # M1: SpMV (scoped by the matvec)
                 queues[qd] = matvec(a)
             elif kind == 1:              # M2/M6/M8: row-wise dot -> sreg
-                sregs = sregs.at[sr].set(_row_dot(a, queues[qb]))
+                with jax.named_scope("vm_dot"):
+                    sregs = sregs.at[sr].set(_row_dot(a, queues[qb]))
             elif kind == 2:              # M3/M4/M7: dst = a ± s·b
-                s = sregs[sr]
-                if neg:
-                    s = -s
-                queues[qd] = a + s[:, None] * queues[qb]
+                with jax.named_scope("vm_axpy"):
+                    s = sregs[sr]
+                    if neg:
+                        s = -s
+                    queues[qd] = a + s[:, None] * queues[qb]
             else:                        # M5: dst = a / b
-                queues[qd] = a / queues[qb]
+                with jax.named_scope("vm_div"):
+                    queues[qd] = a / queues[qb]
         elif w[0] == ITYPE_CTRL:
-            if w[1] == CTRL_ALPHA:       # α = rz / pap
-                sregs = sregs.at[SREG["alpha"]].set(
-                    sregs[SREG["rz"]] / sregs[SREG["pap"]])
-            else:                        # β = rz'/rz ; rz ← rz'
-                new = sregs.at[SREG["beta"]].set(
-                    sregs[SREG["rz_new"]] / sregs[SREG["rz"]])
-                sregs = new.at[SREG["rz"]].set(sregs[SREG["rz_new"]])
+            with jax.named_scope("vm_ctrl"):
+                if w[1] == CTRL_ALPHA:   # α = rz / pap
+                    sregs = sregs.at[SREG["alpha"]].set(
+                        sregs[SREG["rz"]] / sregs[SREG["pap"]])
+                else:                    # β = rz'/rz ; rz ← rz'
+                    new = sregs.at[SREG["beta"]].set(
+                        sregs[SREG["rz_new"]] / sregs[SREG["rz"]])
+                    sregs = new.at[SREG["rz"]].set(sregs[SREG["rz_new"]])
         # NOP words vanish at trace time
     return mem, queues, sregs
 

@@ -75,6 +75,7 @@ bucket back on demand.
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -89,8 +90,8 @@ from repro.core.cg import CGResult
 from repro.core.compile import canonical_program
 from repro.core.isa import BUF, SREG
 from repro.core.metrics import (Metrics, initial_status, is_breakdown,
-                                is_breakdown_codes, status_name,
-                                STATUS_MAXITER, STATUS_RUNNING)
+                                is_breakdown_codes, record, span,
+                                status_name, STATUS_MAXITER, STATUS_RUNNING)
 from repro.core.precision import get_scheme
 from repro.core.vm import BatchedVMState, make_vm_stepper
 from repro.sparse.csr import CSRMatrix
@@ -332,6 +333,16 @@ class _Pool:
     # ---------------------------------------------------------- admission
     def admit(self, a, b, x0, tol, maxiter) -> int:
         """Place one system into a free slot; returns the slot index."""
+        with span("engine.admit.pack"):
+            s, a = self._pack_lane(a)
+        with span("engine.admit.warm"):
+            self._warm_lane(s, a, b, x0, tol, maxiter)
+        return s
+
+    def _pack_lane(self, a):
+        """Pack system ``a`` into a free slot's matrix operand, growing
+        or rebuilding the pool where its geometry asks for it; returns
+        the slot and ``a`` as CSR."""
         free = [s for s, r in enumerate(self.req_of_slot) if r is None]
         if not free and self.slots < self.capacity:
             # Compaction shrank the pool; grow lanes back for this admit.
@@ -409,7 +420,12 @@ class _Pool:
             self.csr_of_slot[s] = a
             self.mat = tuple(_set_lane(arr, s, jnp.asarray(lane))
                              for arr, lane in zip(self.mat, lanes))
+        return s, a
 
+    def _warm_lane(self, s, a, b, x0, tol, maxiter) -> None:
+        """Put the lane's vectors on the device, run its JPCG warm-up
+        and write its VM state."""
+        cfg = self.cfg
         vd = self.scheme.vector_dtype
         n = a.shape[0]
         n_pad = self.state.mem.shape[-1]
@@ -459,7 +475,6 @@ class _Pool:
         self.metrics.bump("admits")
         self.metrics.bump("spmv_calls")          # the warm-up r0 = b - A·x0
         self.metrics.bump("bytes_streamed_est", self._lane_stream_bytes())
-        return s
 
     def _lane_stream_bytes(self) -> int:
         """At-rest nonzero stream per lane per SpMV: packed values +
@@ -492,23 +507,29 @@ class _Pool:
             detect=cfg.detect, interpret=self.interpret, mesh=self.mesh)
         # Materialize the pre-step counters to host before the call —
         # with cfg.donate the state operand is consumed by the stepper.
-        it0 = np.asarray(self.state.it)
-        st0 = np.asarray(self.state.status)
-        if cfg.specialize:
-            stepper = make_vm_stepper(program=self.program_np, **stepper_kw)
-            self.state = stepper(self.mat, self.state, self.tol,
-                                 self.maxiter_vec)
-        else:
-            stepper = make_vm_stepper(**stepper_kw)
-            self.state = stepper(self.program, self.mat, self.state,
-                                 self.tol, self.maxiter_vec)
+        with span("engine.step.pull"):
+            it0 = np.asarray(self.state.it)
+            st0 = np.asarray(self.state.status)
+        with span("engine.step.launch"):
+            if cfg.specialize:
+                stepper = make_vm_stepper(program=self.program_np,
+                                          **stepper_kw)
+                self.state = stepper(self.mat, self.state, self.tol,
+                                     self.maxiter_vec)
+            else:
+                stepper = make_vm_stepper(**stepper_kw)
+                self.state = stepper(self.program, self.mat, self.state,
+                                     self.tol, self.maxiter_vec)
+        with span("engine.step.wait"):
+            it1 = np.asarray(self.state.it)
+            st1 = np.asarray(self.state.status)
         # Accounting: committed iterations plus one discarded program
         # execution per lane that broke down during this step (its tick
         # ran the SpMV before the writes were thrown away).  Frozen
         # lanes' SIMD dead compute is deliberately NOT counted — it
         # streams nothing on the modeled architecture.
-        it_delta = int((np.asarray(self.state.it) - it0).sum())
-        broke = int((is_breakdown_codes(np.asarray(self.state.status))
+        it_delta = int((it1 - it0).sum())
+        broke = int((is_breakdown_codes(st1)
                      & ~is_breakdown_codes(st0)).sum())
         m = self.metrics
         m.bump("chunks")
@@ -600,6 +621,13 @@ class _Pool:
                         if self.req_of_slot[s] is None]
                 sel_l += (o + free)[:t_per]
             sel = np.asarray(sel_l, np.int64)
+        with span("engine.compact"):
+            self._repack(sel, target)
+        return True
+
+    def _repack(self, sel: np.ndarray, target: int) -> None:
+        """Keep the lanes ``sel`` (live ones and free ones to fill the
+        ``target`` lane count), in that order."""
         # The gathers come back replicated under a mesh: lay the lanes out
         # over it again, or every chip would hold the whole pool.
         sel_j = jnp.asarray(sel)
@@ -617,7 +645,6 @@ class _Pool:
         self.n_of_slot = self.n_of_slot[sel]
         self.slots = target
         self.metrics.bump("compactions")
-        return True
 
 
 class SolverEngine:
@@ -639,6 +666,9 @@ class SolverEngine:
         # (retaining every operand would defeat slot recycling otherwise).
         self._meta: Dict[int, tuple] = {}
         self._retried: set = set()
+        # rid -> perf_counter_ns of its first admission, while spans are
+        # recorded (the start of its ``engine.request`` record)
+        self._admitted_ns: Dict[int, int] = {}
 
     def _pool(self, scheme: Optional[str], policy: Optional[str]) -> _Pool:
         scheme = get_scheme(self.cfg.scheme if scheme is None else scheme)
@@ -735,53 +765,67 @@ class SolverEngine:
         ``cfg.escalate_scheme`` pool (the result then carries
         ``retried=True``).
         """
-        self._harvest()        # a lane done since the last tick frees its slot
-        pool = self._pool(scheme, policy)
-        s = pool.admit(a, b, x0, tol, maxiter)
         rid = self._next_id
+        with span("engine.submit", rid=rid):
+            self._harvest()    # a lane done since the last tick frees its slot
+            self._admit(self._pool(scheme, policy), rid, a, b, x0, tol,
+                        maxiter)
         self._next_id += 1
-        pool.req_of_slot[s] = rid
         if self.cfg.escalate_fp64:
             self._meta[rid] = (a, b, x0, tol, maxiter,
                                self.cfg.policy if policy is None else policy)
         return rid
 
+    def _admit(self, pool: _Pool, rid: int, a, b, x0, tol, maxiter) -> None:
+        with span("engine.admit", rid=rid) as sp:
+            s = pool.admit(a, b, x0, tol, maxiter)
+        pool.req_of_slot[s] = rid
+        if sp is not None:
+            self._admitted_ns.setdefault(rid, sp.start_ns)
+
     def step(self) -> Dict[int, CGResult]:
         """One chunked tick (≤ ``chunk_iters`` iterations for every live
         lane in every pool); harvests and frees slots that finished,
         returning ``{request_id: CGResult}``."""
-        for pool in self._pools.values():
-            if pool.any_active:
-                pool.step()
-        done = self._harvest()
-        for pool in self._pools.values():
-            pool.maybe_compact()
+        with span("engine.step"):
+            for pool in self._pools.values():
+                if pool.any_active:
+                    pool.step()
+            done = self._harvest()
+            for pool in self._pools.values():
+                pool.maybe_compact()
         return done
 
     def _harvest(self) -> Dict[int, CGResult]:
-        raw: Dict[int, CGResult] = {}
-        for pool in self._pools.values():
-            raw.update(pool.harvest())
-        done: Dict[int, CGResult] = {}
-        for rid, res in raw.items():
-            if self._should_escalate(rid, res):
-                # One retry at the escalation scheme: re-admit the
-                # retained operands into the target pool under the SAME
-                # request id — the caller sees one request, one (final)
-                # result, with retried=True.
-                a, b, x0, tol, maxiter, policy = self._meta[rid]
-                pool = self._pool(self.cfg.escalate_scheme, policy)
-                s = pool.admit(a, b, x0, tol, maxiter)
-                pool.req_of_slot[s] = rid
-                self._retried.add(rid)
-                self._metrics.bump("escalations")
-                continue
-            res.retried = rid in self._retried
-            self._metrics.record_exit(res.status)
-            self._meta.pop(rid, None)
-            self._retried.discard(rid)
-            done[rid] = res
-        self.results.update(done)
+        with span("engine.harvest"):
+            raw: Dict[int, CGResult] = {}
+            for pool in self._pools.values():
+                raw.update(pool.harvest())
+            done: Dict[int, CGResult] = {}
+            for rid, res in raw.items():
+                if self._should_escalate(rid, res):
+                    # One retry at the escalation scheme: re-admit the
+                    # retained operands into the target pool under the
+                    # SAME request id — the caller sees one request, one
+                    # (final) result, with retried=True.
+                    a, b, x0, tol, maxiter, policy = self._meta[rid]
+                    self._admit(self._pool(self.cfg.escalate_scheme, policy),
+                                rid, a, b, x0, tol, maxiter)
+                    self._retried.add(rid)
+                    self._metrics.bump("escalations")
+                    continue
+                res.retried = rid in self._retried
+                self._metrics.record_exit(res.status)
+                self._meta.pop(rid, None)
+                self._retried.discard(rid)
+                done[rid] = res
+            self.results.update(done)
+        if done and self._admitted_ns:
+            now = time.perf_counter_ns()
+            for rid in done:
+                start = self._admitted_ns.pop(rid, None)
+                if start is not None:
+                    record("engine.request", start, now, rid)
         return done
 
     def _should_escalate(self, rid: int, res: CGResult) -> bool:
