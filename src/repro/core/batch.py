@@ -48,13 +48,16 @@ scheme tolerance; iteration counts agree within ±1).
 Bucket policy / compile cache
 -----------------------------
 Heterogeneous problems are padded to a shared shape before stacking:
-every structural dimension (row blocks, slabs, slab length / ELL slots,
-col tiles) is rounded UP to a power-of-two bucket edge
-(:func:`repro.sparse.stacking.bucket_up`), so traffic whose sizes vary
-continuously collapses onto ``O(log n)`` distinct compiled shapes — the
-batched restatement of ``cg.py``'s "one compiled program per padded
-bucket".  Executables are held in an explicit cache keyed by
-``(backend, batch, bucket dims, scheme, maxiter, trace)``;
+every structural dimension (rows, row-ELL / SELL widths, ELL slots) is
+rounded UP to a power-of-two bucket edge
+(:func:`repro.sparse.stacking.bucket_up`), and the Pallas ELLPACK
+operand's row blocks, slabs and col tiles to an eighth-octave edge
+(:func:`repro.sparse.stacking.fine_bucket_up`, < 12.5% padding), so
+traffic whose sizes vary continuously collapses onto ``O(log n)``
+distinct compiled shapes — the batched restatement of ``cg.py``'s "one
+compiled program per padded bucket".  Executables are held in an
+explicit cache keyed by ``(backend, batch, bucket dims, scheme, maxiter,
+trace)``;
 :func:`batch_cache_info` exposes hit/miss counts so tests (and the
 serving engine) can assert reuse.
 

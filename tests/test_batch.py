@@ -129,6 +129,27 @@ class TestBucketCache:
         assert [bucket_up(x) for x in (1, 2, 3, 5, 8, 9)] == \
             [1, 2, 4, 8, 8, 16]
 
+    def test_ellpack_fine_bucket_matches_exact(self):
+        """A Pallas ELLPACK bag whose row blocks (104) and slabs (7) fall
+        off the power-of-two edges: ``bucket=True`` keeps both (104 =
+        13·8 is an eighth-octave edge) and pads only the slots 6 -> 8,
+        with exact zeros — so it solves bit-identically to
+        ``bucket=False``."""
+        from repro.sparse.ellpack import csr_to_ellpack
+        from repro.sparse.stacking import stack_ellpack
+        probs = [diag_dominant_spd(832, nnz_per_row=5, dominance=1.2,
+                                   seed=3), poisson_2d(12)]
+        ells = [csr_to_ellpack(a, **BK) for a in probs]
+        assert stack_ellpack(ells, bucket=False).vals.shape[1:] == \
+            (104, 7, 6, 8)
+        assert stack_ellpack(ells).vals.shape[1:] == (104, 7, 8, 8)
+        kw = dict(tol=1e-10, maxiter=300, backend="pallas",
+                  layout="ellpack", **BK)
+        fine = jpcg_solve_batched(probs, bucket=True, **kw)
+        exact = jpcg_solve_batched(probs, bucket=False, **kw)
+        assert all(r.status == "CONVERGED" for r in exact)
+        assert_results_bit_identical(fine, exact, status=True, rr=True)
+
 
 class TestSolverEngine:
     def test_admission_and_harvest(self):

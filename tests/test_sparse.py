@@ -199,3 +199,77 @@ class TestStacking:
             np.add.at(y, s.rows[g], s.vals[g] * x[s.gcols[g]])
             ref = bell_spmv_reference(m, x[: m.shape[1]])
             np.testing.assert_allclose(y[: m.shape[0]], ref)
+
+
+class TestFineBucket:
+    """Eighth-octave edges of the ELLPACK operand against the
+    power-of-two edges every other stacked layout keeps."""
+
+    def test_fine_bucket_up_edges(self):
+        from repro.sparse.stacking import bucket_up, fine_bucket_up
+        assert [fine_bucket_up(x) for x in range(1, 17)] == \
+            list(range(1, 17))
+        assert [fine_bucket_up(x) for x in (17, 19, 33, 2197, 4394)] == \
+            [18, 20, 36, 2304, 4608]
+        prev = 0
+        for x in range(1, 10**6 + 1):
+            e = fine_bucket_up(x)
+            assert x <= e < x * 1.125
+            assert e >= prev
+            prev = e
+        for x in range(1, 5000):
+            e = fine_bucket_up(x)
+            assert fine_bucket_up(e) == e
+            assert e <= bucket_up(x)
+        for k in range(31):
+            assert fine_bucket_up(1 << k) == 1 << k
+
+    def _ellpacks(self):
+        return [csr_to_ellpack(a, block_rows=8, col_tile=16) for a in
+                (diag_dominant_spd(300, nnz_per_row=6, seed=3),
+                 poisson_2d(13), tridiagonal_spd(250),
+                 random_spd(6, cond=10.0, seed=1))]
+
+    def test_stack_ellpack_fine_edges(self):
+        from repro.sparse.ellpack import EllpackMatrix
+        from repro.sparse.stacking import (bucket_up, fine_bucket_up,
+                                           stack_ellpack)
+        ells = self._ellpacks()
+        B, T, E, n_tiles = (max(getattr(m, k) for m in ells) for k in
+                            ("n_row_blocks", "n_slabs", "ell",
+                             "n_col_tiles"))
+        # the bag reaches past 16 off the power-of-two edges; its slot
+        # count (6, the dense lane) is off them too
+        assert min(B, T, n_tiles) > 16
+        for d in (B, T, E, n_tiles):
+            assert fine_bucket_up(d) < bucket_up(d)
+        s = stack_ellpack(ells)
+        assert s.vals.shape[1:] == (fine_bucket_up(B), fine_bucket_up(T),
+                                    bucket_up(E), 8)
+        assert s.local_cols.shape == s.vals.shape
+        assert s.tile_cols.shape == s.vals.shape[:3]
+        assert s.n_col_tiles == fine_bucket_up(n_tiles)
+        exact = stack_ellpack(ells, bucket=False)
+        assert exact.vals.shape[1:] == (B, T, E, 8)
+        assert exact.n_col_tiles == n_tiles
+        # padding is exact zeros: every lane's product is unchanged
+        for g, m in enumerate(ells):
+            lane = EllpackMatrix(s.tile_cols[g], s.vals[g], s.local_cols[g],
+                                 m.shape, m.block_rows, m.col_tile, m.nnz)
+            x = np.random.default_rng(g).standard_normal(m.shape[1])
+            np.testing.assert_array_equal(ellpack_spmv_reference(lane, x),
+                                          ellpack_spmv_reference(m, x))
+
+    def test_sell_rowell_stay_power_of_two(self):
+        from repro.sparse.stacking import (bucket_up, fine_bucket_up,
+                                           stack_rowell, stack_sell)
+        bag = [diag_dominant_spd(300, nnz_per_row=6, seed=3),
+               poisson_2d(13), tridiagonal_spd(250)]
+        assert fine_bucket_up(300) < bucket_up(300)
+        r = stack_rowell(bag)
+        assert r.padded_rows == bucket_up(300) == 512
+        assert r.width == bucket_up(r.width)
+        s = stack_sell(bag)
+        assert s.padded_rows == 512
+        for rows, w in s.groups:
+            assert w == 0 or w == bucket_up(w)

@@ -25,7 +25,7 @@ from repro.core.vm import make_vm_runner
 from repro.kernels.dot import dot3_pallas, dot_pallas
 from repro.kernels.fused_phase import phase2_pallas, phase3_pallas
 from repro.kernels.spmv import spmv_pallas_batched, spmv_pallas_sell
-from repro.sparse.stacking import bucket_up
+from repro.sparse.stacking import bucket_up, fine_bucket_up
 
 _spec = importlib.util.spec_from_file_location(
     "chip_smoke", pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py")
@@ -89,6 +89,25 @@ def test_spmv_ellpack_batched(shape):
         shape(blk, SCHEME.matrix_dtype), shape(blk, jnp.int32),
         shape((G, N_PAD // smoke.COL_TILE, smoke.COL_TILE), jnp.float32))
     assert _kernels(c) >= G          # one launch per system
+
+
+def test_spmv_ellpack_batched_eighth_octave(shape):
+    """The grid off the power-of-two edges: HPCG 104³'s two lanes as
+    stacked, 4394 row blocks -> 4608 (9·2^9), 6 slabs, 9 slots -> 16,
+    2197 x tiles -> 2304."""
+    n = 104 ** 3
+    B = fine_bucket_up(-(-n // smoke.BLOCK_ROWS))
+    n_tiles = fine_bucket_up(-(-n // smoke.COL_TILE))
+    assert (B, n_tiles) == (9 << 9, 9 << 8)
+    g, T, E = 2, 6, 16
+    blk = (g, B, T, E, smoke.BLOCK_ROWS)
+    c = _compile(
+        jax.jit(lambda tc, v, lc, x: spmv_pallas_batched(
+            tc, v, lc, x, scheme=SCHEME)),
+        shape((g, B, T), jnp.int32),
+        shape(blk, SCHEME.matrix_dtype), shape(blk, jnp.int32),
+        shape((g, n_tiles, smoke.COL_TILE), jnp.float32))
+    assert _kernels(c) >= g          # one launch per system
 
 
 def test_spmv_sell(shape):
