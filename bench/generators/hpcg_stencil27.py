@@ -12,6 +12,10 @@ import scipy.sparse as sp
 
 from bench import systems as S
 
+#: the configuration's overrides that make the operator small enough for
+#: the CPU tests (a 512-row grid)
+SMALL = {"params": {"nx": 8, "ny": 8, "nz": 8}}
+
 
 def hpcg_stencil27(nx: int, ny: int, nz: int) -> sp.csr_matrix:
     """HPCG's 27-point operator on an ``nx x ny x nz`` grid.

@@ -18,11 +18,14 @@ window will use (set-up, ``setup_s``), drives the traffic for
 ``--seconds``, reads the device's peak memory, frees the program's
 state, and checks every answer of the window against the float64
 reference (``bench/reference.py``).  ``--trace 1`` records the window
-with the profiler and reports the per-layer metrics instead of the
-end-to-end ones.  The last line of standard output is one JSON object
-(``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
-``breakdown`` when traced, and ``check`` last); the last lines of
-standard error give each number compared beside its limit.
+with the profiler and the program's spans, and reports the per-layer
+metrics instead of the end-to-end ones, with a ``breakdown`` of the
+device's time whose idle gaps are named by the innermost span open in
+each, the program's or the benchmark's (:func:`breakdown`).  The last
+line of standard output is one JSON object (``correct``, ``attempted``,
+``failed``, ``metrics``, ``device``, ``breakdown`` when traced, and
+``check`` last); the last lines of standard error give each number
+compared beside its limit.
 
 It exits non-zero, and prints no result, without a TPU or with fewer
 chips than the cell asks for, and outside a checkout of the repository.
@@ -68,6 +71,17 @@ def cell_of(spec: dict, workload: str) -> dict:
         if w["name"] == workload:
             return w
     raise SystemExit(f"run.py: no workload {workload!r} in BENCHMARK.json")
+
+
+def config_of(cell: dict) -> dict:
+    """The configuration a cell of ``BENCHMARK.json`` names, as its file
+    ``bench/configs/<config>.json`` holds it."""
+    return load_json(BENCH / "configs" / f"{cell['config']}.json")
+
+
+def traffic_of(cell: dict) -> dict:
+    """The traffic mix a cell names, ``bench/traffic/<traffic>.json``."""
+    return load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
 
 
 def untagged(name: str, have) -> str:
@@ -169,10 +183,8 @@ def setup(workload: str, *, require_tpu: bool = True, spec: dict = None,
         raise SystemExit(f"run.py: no checkout of the program around {ROOT}")
     spec = spec or load_json(ROOT / "BENCHMARK.json")
     cell = cell_of(spec, workload)
-    cfg = load_json(BENCH / "configs" / f"{cell['config']}.json")
-    traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
-    for key, val in (overrides or {}).get("cfg", {}).items():
-        cfg[key] = {**cfg[key], **val} if isinstance(val, dict) else val
+    cfg = config_of(cell)
+    traffic = traffic_of(cell)
     traffic.update((overrides or {}).get("traffic", {}))
 
     for path in (ROOT, ROOT / "src"):
@@ -184,6 +196,7 @@ def setup(workload: str, *, require_tpu: bool = True, spec: dict = None,
     if require_tpu:
         enable_cache()
     from bench import loadgen, systems
+    cfg = systems.overridden(cfg, (overrides or {}).get("cfg", {}))
 
     compiles = CompileCount()
     t0 = time.perf_counter()
@@ -200,18 +213,31 @@ def setup(workload: str, *, require_tpu: bool = True, spec: dict = None,
                                  loop=loop, compiles=compiles)
 
 
+def breakdown(view) -> dict:
+    """A traced run's ``breakdown``: the device operations that took most
+    time and the longest idle gaps, named by the program's spans and
+    scopes (``bench/programtrace.py``).  Where the program recorded none,
+    that naming is ``bench/tracefile.py``'s: the benchmark's spans alone."""
+    from bench import programtrace
+    named = programtrace.view_of(view).trace
+    return {"device_ops": named["device_ops"],
+            "idle_gaps": named["idle_gaps"]}
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_start: float = T_START, **kw) -> dict:
     """One run of one cell; returns the result object (see module doc).
     ``kw`` goes to :func:`setup`."""
     built = setup(workload, **kw)
     import jax
-    from bench import loadgen, reference, roofline, tracefile
+    from bench import loadgen, programtrace, reference, roofline, tracefile
     spec, cfg, devs, a = built.spec, built.cfg, built.devs, built.a
     loop = built.loop
     kind = "per_layer" if trace else "end_to_end"
     wanted = metrics_of(spec, workload, kind)
     readers = {m["name"]: reader(m["name"]) for m in wanted} if trace else {}
+    if trace:
+        programtrace.arm()
     kernels = sorted({k for r in readers.values()
                       for k in getattr(r, "KERNELS", ())})
     loop.prepare(seed, seconds)
@@ -267,8 +293,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                       for m in wanted if values.get(m["name"]) is not None}
     out["device"] = device
     if trace:
-        out["breakdown"] = {"device_ops": view.trace["device_ops"],
-                            "idle_gaps": view.trace["idle_gaps"]}
+        out["breakdown"] = breakdown(view)
     out["window_compiles"] = window_compiles.count
     out["check"] = check.lines()
 

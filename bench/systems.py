@@ -10,11 +10,14 @@ program.  It defines
   ``matrix_seed``), so that every run packs the same shapes and finds
   its programs in the compile cache;
 * ``rhs(cfg, a, seed, j)`` -- the run's ``j``-th right-hand side,
-  drawn from the run's seed.
+  drawn from the run's seed;
+* ``SMALL`` -- the configuration's overrides (``{"params": {...}}``,
+  merged as :func:`overridden` merges) that make the operator small
+  enough for the CPU; the benchmark's tests run every cell at it.
 
 Nothing here imports the system under test.  A new configuration with a
 new operator adds its generator's file and names it in its
-configuration's ``generator``.
+configuration's ``generator``; no other file changes.
 """
 from __future__ import annotations
 
@@ -45,6 +48,15 @@ def generator(name: str) -> types.ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def overridden(cfg: dict, overrides: dict) -> dict:
+    """A copy of ``cfg`` with the entries of ``overrides`` in place; an
+    entry that is a dict is merged one level deep."""
+    out = dict(cfg)
+    for key, val in overrides.items():
+        out[key] = {**out[key], **val} if isinstance(val, dict) else val
+    return out
 
 
 class Systems:

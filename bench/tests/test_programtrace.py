@@ -15,6 +15,7 @@ import scipy.sparse as sp
 from bench import loadgen as L
 from bench import programtrace as P
 from bench import run
+from bench import systems as S
 from bench import tracefile as T
 from bench.run import reader
 
@@ -310,9 +311,10 @@ def test_a_program_without_the_recorder_gives_nothing(monkeypatch):
 
 
 # ------------------------------------------- the program, on the CPU
-SMALL = {"hpcg_104.rhs2": {"params": {"nx": 8, "ny": 8, "nz": 8}},
-         "graph500_s15.bag8": {"params": {"scale": 8}},
-         "graph500_s15.stream": {"params": {"scale": 8}}}
+def small(cell):
+    """The cell's configuration overrides at its generator's ``SMALL``."""
+    gen = run.config_of(run.cell_of(SPEC, cell))["generator"]
+    return S.generator(gen).SMALL
 
 
 @pytest.mark.parametrize("cell,metric", [
@@ -323,7 +325,7 @@ def test_reader_on_a_small_window(cell, metric):
     """Loading the reader switches the program's recorder on; a window
     of the cell's traffic then leaves spans that the reader reads."""
     built = run.setup(cell, require_tpu=False,
-                      overrides={"cfg": SMALL[cell],
+                      overrides={"cfg": small(cell),
                                  "traffic": {"drain_s": 3.0}})
     r = reader(metric)
     built.loop.prepare(5, 1.0)
@@ -342,3 +344,56 @@ def test_reader_on_a_small_window(cell, metric):
     # recording stopped with the read: the next window records nothing
     from repro.core import metrics as M
     assert M.span("batch.solve") is M.span("engine.step")
+
+
+# ------------------------------------------ the result line's breakdown
+def run_view(events, monkeypatch, tmp_path, spans):
+    """A traced run's view as ``bench/run.py`` builds it from ``events``,
+    with its per-layer readers, and a program whose recorder hands back
+    ``spans``."""
+    monkeypatch.setattr(P, "TRACES", tmp_path)
+    monkeypatch.setattr(P, "read_events", lambda d: events)
+    monkeypatch.setattr(P, "_program", lambda: types.SimpleNamespace(
+        start_spans=lambda: None, stop_spans=lambda: list(spans)))
+    readers = {m["name"]: reader(m["name"]) for m in SPEC["per_layer"]}
+    kernels = sorted({k for r in readers.values()
+                      for k in getattr(r, "KERNELS", ())})
+    v = trace_view(T.summarize(events, kernels=kernels))
+    del v.program
+    return v, readers
+
+
+def test_breakdown_names_gaps_by_program_span(monkeypatch, tmp_path):
+    """On the recorded stream trace with the program's spans, the result
+    line's gaps split the same idle time as the ``bench/`` spans do, its
+    largest gap is the engine's admission and not the benchmark's
+    ``submit``, and no per-layer number moves."""
+    events = P.load_events(str(FIXTURES / "stream_spans_v5e.events.json.gz"))
+    v, readers = run_view(events, monkeypatch, tmp_path,
+                          records(("engine.admit", 0, 1, 0)))
+    old = {name: r.read(v) for name, r in readers.items()}
+    got = run.breakdown(v)
+    assert {name: r.read(v) for name, r in readers.items()} == old
+    renamed = trace_view(P.summarize(events, kernels=sorted(v.trace[
+        "kernel_s"])))
+    renamed.program = v.program
+    assert {name: r.read(renamed) for name, r in readers.items()} == old
+    assert abs(sum(s for _, s in got["idle_gaps"]) -
+               sum(s for _, s in v.trace["idle_gaps"])) < 1e-9
+    assert got["idle_gaps"][0][0] in ("engine.admit.pack",
+                                      "engine.admit.warm")
+    assert "submit" not in dict(got["idle_gaps"])
+    assert got["device_ops"][0][0].endswith("@m1_xla_sell")
+    assert got == {k: v.program.trace[k] for k in ("device_ops",
+                                                   "idle_gaps")}
+
+
+@pytest.mark.parametrize("name", ["bag8_v5e", "stream_v5e"])
+def test_breakdown_without_program_spans_is_the_bench_view(
+        name, monkeypatch, tmp_path):
+    """The accepted benchmark's recorded traces hold no program span: the
+    result line's breakdown names them as ``bench/tracefile.py`` does."""
+    events = P.load_events(str(FIXTURES / f"{name}.events.json.gz"))
+    v, _ = run_view(events, monkeypatch, tmp_path, [])
+    assert run.breakdown(v) == {"device_ops": v.trace["device_ops"],
+                                "idle_gaps": v.trace["idle_gaps"]}

@@ -14,23 +14,20 @@ import time
 import numpy as np
 import pytest
 
-from bench import run
+from bench import run, systems
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
-STREAM = next(w["name"] for w in SPEC["workloads"] if w["traffic"] == "stream")
-#: each generator's small size, for the CPU
-SMALL = {"hpcg_stencil27": {"params": {"nx": 8, "ny": 8, "nz": 8}},
-         "graph500_laplacian": {"params": {"scale": 8}}}
+LOOP = {w["name"]: run.traffic_of(w)["loop"] for w in SPEC["workloads"]}
+STREAM = next(c for c in CELLS if LOOP[c] == "open")
 SECONDS = 1.5
 
 
 def small(cell, **solver):
-    config = next(w["config"] for w in SPEC["workloads"] if w["name"] == cell)
-    gen = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
-                     .read_text())["generator"]
-    cfg = dict(SMALL[gen])
+    """Overrides that run ``cell`` at its generator's ``SMALL`` size."""
+    gen = run.config_of(run.cell_of(SPEC, cell))["generator"]
+    cfg = dict(systems.generator(gen).SMALL)
     if solver:
         cfg["solver"] = solver
     return {"cfg": cfg, "traffic": {"drain_s": 3.0}}
@@ -107,8 +104,7 @@ def unchanged(results):
             for r in results]
 
 
-@pytest.mark.parametrize("cell", [c for c in CELLS
-                                  if c.endswith(("rhs2", "bag8"))])
+@pytest.mark.parametrize("cell", [c for c in CELLS if LOOP[c] == "closed"])
 @pytest.mark.parametrize("fault", ["altered", "half", "unchanged"])
 def test_bag_faults_are_caught(cell, fault, monkeypatch):
     import repro.core as core

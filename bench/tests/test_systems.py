@@ -122,9 +122,17 @@ def test_operator_from_matrix_seed_only():
 @pytest.mark.parametrize("path", sorted(CONFIGS.glob("*.json")),
                          ids=lambda p: p.stem)
 def test_every_configuration_finds_its_generator(path):
+    """Its generator defines ``build``, ``rhs`` and ``SMALL``, the CPU
+    test size, whose overrides name keys the configuration has."""
     cfg = json.loads(path.read_text())
     gen = S.generator(cfg["generator"])
     assert callable(gen.build) and callable(gen.rhs)
+    assert isinstance(getattr(gen, "SMALL", None), dict), \
+        f"{S.GENERATORS / cfg['generator']}.py defines no SMALL"
+    for key, val in gen.SMALL.items():
+        assert key in cfg
+        if isinstance(val, dict):
+            assert set(val) <= set(cfg[key]), key
 
 
 def test_unknown_generator_is_an_error():
