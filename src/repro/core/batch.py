@@ -73,13 +73,15 @@ from __future__ import annotations
 
 import itertools
 from functools import partial
-from typing import List, NamedTuple, Optional, Sequence
+from typing import Any, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import dfloat
 from repro.core.cg import CGResult
+from repro.core.dfloat import hi, select
 from repro.core.metrics import (advance_status, finalize_status,
                                 initial_status, is_breakdown,
                                 solver_metrics, span, status_name,
@@ -110,10 +112,64 @@ class BatchedCGState(NamedTuple):
     rr: jax.Array       # [G] per-lane ‖r‖² — the termination scalars
     active: jax.Array   # bool[G] live-lane mask
     trace: jax.Array    # [G, maxiter] rr per iteration, or [G, 0]
+    rep: Any = None     # Replacement under a wide scheme, else None
+
+
+class Replacement(NamedTuple):
+    """Per-lane residual-replacement state of a wide scheme."""
+
+    rr_ref: jax.Array   # float32[G]: ‖r‖² at the lane's last replacement
+    count: jax.Array    # int32[G]: replacements so far
+
+
+#: a lane replaces its residual once ‖r‖ fell below DELTA of its value at
+#: the last replacement (squared: ‖r‖² below DELTA² of it)
+DELTA = 0.1
+
+#: a wide lane stops on the device at ‖r‖ ≤ MARGIN·√tol (``rr ≤ MARGIN²·tol``):
+#: the caller gets the device's x rounded to float64, and checks it with a
+#: float64 ``b − A·x`` that rounds again.  On ecology2's 1000 × 1000 grid
+#: (‖x‖∞ = 1.2e6) the two add 2.8e-10 and 1.5e-10 of ‖b‖, so a lane stopped
+#: at 1e-9 read 1.05e-9; at half of it the float64 x meets the bound
+MARGIN = 0.5
 
 
 def _row_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    if isinstance(a, dfloat.DF):
+        return a.dot(b)
     return jnp.sum(a * b, axis=-1)
+
+
+def replace_residual(matvec, b, diag, x, r, rz, rr, rep: Replacement, upd,
+                     tol):
+    """Residual replacement for a wide scheme, after a tick's updates.
+
+    A committed lane (``upd``) whose recurrence ``‖r‖²`` fell below
+    ``DELTA²`` of its value at the last replacement, or met ``tol`` (the
+    device's, already scaled by ``MARGIN²``), takes ``r = b − A·x`` from
+    the same SpMV, and ``rz``/``rr`` from that ``r``; ``p`` is kept.  So
+    a lane's ``rr`` meets ``tol`` at the end of a tick only on a
+    replaced, true residual: the callers' ``rr ≤ tol`` tests then latch
+    ``CONVERGED`` on it and on nothing else.  One conditional SpMV of
+    every lane when any lane needs one; its other device work carries
+    the scope ``vm_replace``.
+    """
+    with jax.named_scope("vm_replace"):
+        rrh = hi(rr)
+        need = upd & ((rrh <= DELTA * DELTA * rep.rr_ref) | (rrh <= tol))
+
+        def true_residual(_):
+            rt = b - matvec(x)
+            return rt, _row_dot(rt, rt / diag), _row_dot(rt, rt)
+
+        rt, rzt, rrt = jax.lax.cond(jnp.any(need), true_residual,
+                                    lambda _: (r, rz, rr), None)
+        r = select(need[:, None], rt, r)
+        rz = select(need, rzt, rz)
+        rr = select(need, rrt, rr)
+        rep = Replacement(jnp.where(need, hi(rr), rep.rr_ref),
+                          rep.count + need.astype(jnp.int32))
+    return r, rz, rr, rep
 
 
 # --------------------------------------------------------------- matvecs
@@ -272,6 +328,20 @@ def batched_matvec_sell(cols, vals, iperm, x, *, groups,
         return y.astype(scheme.vector_dtype)
 
 
+def batched_matvec_ellpack_df(tile_cols, vals, local_cols, x, *,
+                              col_tile: int, n_col_tiles: int,
+                              interpret: bool) -> dfloat.DF:
+    """Batched double-fp32 Pallas SpMV of a wide ``x`` (:class:`DF`, or
+    :class:`TF` for the solution) -> :class:`DF`."""
+    from repro.kernels.spmv_df import spmv_pallas_df_batched, x_words_tiles
+    n = x.shape[-1]
+    xt = x_words_tiles(x.planes, n_col_tiles, col_tile)
+    yh, yl = spmv_pallas_df_batched(tile_cols, vals, local_cols, xt,
+                                    words=len(x.planes), interpret=interpret)
+    G = yh.shape[0]
+    return dfloat.DF(yh.reshape(G, -1)[:, :n], yl.reshape(G, -1)[:, :n])
+
+
 def batched_matvec_ellpack(tile_cols, vals, local_cols, x, *,
                            col_tile: int, n_col_tiles: int,
                            scheme: PrecisionScheme,
@@ -299,14 +369,18 @@ def _batched_init(matvec, diag, b, x0, *, maxiter, scheme, with_trace,
     rz = _row_dot(r, z)
     rr = _row_dot(r, r)
     trace = jnp.zeros((G, maxiter if with_trace else 0), dtype=vd)
+    rep = None
+    if scheme.wide:
+        rep = Replacement(hi(rr), jnp.zeros(G, jnp.int32))
     return BatchedCGState(
         k=jnp.zeros((), jnp.int32), it=jnp.zeros(G, jnp.int32),
-        status=initial_status(rr, tol, detect=detect),
-        x=x0, r=r, p=p, rz=rz, rr=rr, active=rr > tol, trace=trace)
+        status=initial_status(hi(rr), tol, detect=detect),
+        x=x0, r=r, p=p, rz=rz, rr=rr, active=hi(rr) > tol, trace=trace,
+        rep=rep)
 
 
 def _batched_body(matvec, diag, tol, maxiter_vec=None, *, bound=None,
-                  write_trace=True, detect=True):
+                  write_trace=True, detect=True, b=None):
     """Masked VSR iteration over all lanes.
 
     Frozen (converged) lanes still flow through the arithmetic — that is
@@ -333,6 +407,9 @@ def _batched_body(matvec, diag, tol, maxiter_vec=None, *, bound=None,
     lanes see the identical dataflow with or without detection (the
     commit mask degenerates to ``keep``), which ``tests/test_health.py``
     locks bit-for-bit.
+
+    ``b`` (a wide scheme's right-hand side) arms
+    :func:`replace_residual` after the tick's updates.
     """
 
     def body(s: BatchedCGState) -> BatchedCGState:
@@ -343,14 +420,19 @@ def _batched_body(matvec, diag, tol, maxiter_vec=None, *, bound=None,
         if bound is not None:
             go = go & (s.k < bound)
         keep = s.active & go
-        upd, bd_i, bd_n = tick_health(keep, pap, alpha, beta, rr_new,
-                                      detect=detect)
+        upd, bd_i, bd_n = tick_health(keep, hi(pap), hi(alpha), hi(beta),
+                                      hi(rr_new), detect=detect)
+        rep = s.rep
+        if b is not None:
+            r_new, rz_new, rr_new, rep = replace_residual(
+                matvec, b, diag, x_new, r_new, rz_new, rr_new, rep, upd, tol)
         kv = upd[:, None]
-        x = jnp.where(kv, x_new, s.x)
-        r = jnp.where(kv, r_new, s.r)
-        p = jnp.where(kv, p_new, s.p)
-        rz = jnp.where(upd, rz_new, s.rz)
-        rr = jnp.where(upd, rr_new, s.rr)
+        x = select(kv, x_new, s.x)
+        r = select(kv, r_new, s.r)
+        p = select(kv, p_new, s.p)
+        rz = select(upd, rz_new, s.rz)
+        rr = select(upd, rr_new, s.rr)
+        rr_new = hi(rr_new)
         it = s.it + upd.astype(jnp.int32)
         if write_trace and s.trace.shape[1]:
             safe_k = jnp.minimum(s.k, s.trace.shape[1] - 1)
@@ -359,7 +441,7 @@ def _batched_body(matvec, diag, tol, maxiter_vec=None, *, bound=None,
                           s.trace[:, safe_k]))
         else:
             trace = s.trace
-        live = rr > tol
+        live = hi(rr) > tol
         if maxiter_vec is not None:
             live = live & (it < maxiter_vec)
         if detect:
@@ -371,7 +453,7 @@ def _batched_body(matvec, diag, tol, maxiter_vec=None, *, bound=None,
         active = jnp.where(keep, live, s.active)
         return BatchedCGState(k=s.k + go.astype(jnp.int32), it=it,
                               status=status, x=x, r=r, p=p, rz=rz, rr=rr,
-                              active=active, trace=trace)
+                              active=active, trace=trace, rep=rep)
 
     return body
 
@@ -474,6 +556,17 @@ def _matvec_factory(*, backend, scheme, layout=None, groups=None,
     for the Pallas ellpack path.
     """
     layout = layout or ("rowell" if backend == "xla" else "ellpack")
+    if scheme.wide:
+        if (backend, layout) != ("pallas", "ellpack"):
+            raise ValueError(f"scheme {scheme.name!r} runs on the Pallas "
+                             "ELLPACK path only")
+
+        def matvec_of(mat):
+            tc, v, lc = mat
+            return lambda x: batched_matvec_ellpack_df(
+                tc, v, lc, x, col_tile=col_tile, n_col_tiles=n_col_tiles,
+                interpret=interpret)
+        return matvec_of
     if layout == "sell":
         if groups is None:
             raise ValueError("layout='sell' needs the static groups= "
@@ -540,14 +633,15 @@ def _make_runner(*, backend, scheme, maxiter, with_trace, layout=None,
                            scheme=scheme, with_trace=with_trace, tol=tol,
                            detect=detect)
         tick = _batched_body(matvec, diag, tol, bound=maxiter,
-                             write_trace=not hoist_trace, detect=detect)
+                             write_trace=not hoist_trace, detect=detect,
+                             b=b if scheme.wide else None)
 
         def cond(s):
             return (s.k < maxiter) & jnp.any(s.active)
 
         out = _run_chunked(cond, tick, st, steps=steps_per_sync,
                            with_trace=with_trace, maxiter=maxiter,
-                           rr_of=lambda s: s.rr)
+                           rr_of=lambda s: hi(s.rr))
         return out._replace(status=finalize_status(out.status))
 
     fn = jax.jit(run, donate_argnums=(2, 3) if donate else ())
@@ -576,9 +670,16 @@ def _as_csr(a) -> CSRMatrix:
 
 def _pad_stack(vecs: Sequence[np.ndarray], n_pad: int, fill: float,
                dtype) -> jnp.ndarray:
+    """The lanes' vectors padded with ``fill`` to ``[G, n_pad]`` on the
+    device at ``dtype``; ``dtype`` :class:`~repro.core.dfloat.DF` /
+    :class:`~repro.core.dfloat.TF` gives their fp32 words."""
     out = np.full((len(vecs), n_pad), fill, dtype=np.float64)
     for g, v in enumerate(vecs):
         out[g, : v.shape[0]] = np.asarray(v, dtype=np.float64)
+    if dtype is dfloat.DF:
+        return dfloat.df_from_f64(out)
+    if dtype is dfloat.TF:
+        return dfloat.tf_from_f64(out)
     return jnp.asarray(out, dtype=dtype)
 
 
@@ -647,6 +748,14 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
     :func:`repro.core.metrics.solver_metrics` counters (iterations,
     SpMV-call and streamed-byte estimates, exit histogram).
 
+    A wide scheme (``tpu_v3_df``: double-fp32 vectors, x in three fp32
+    words; :mod:`repro.core.dfloat`) runs the Pallas ELLPACK path under
+    the phases engine or the specialized VM, without a mesh.  Its lanes
+    replace their residual on the device (:func:`replace_residual`),
+    ``CONVERGED`` means a replaced residual met ``MARGIN²·tol`` (so the
+    float64 ``x`` meets ``tol``), ``rr`` is that residual, each result's
+    ``replacements`` counts them, and ``x`` comes back as float64.
+
     ``mesh`` (a 1-D :class:`jax.sharding.Mesh`, e.g.
     :func:`repro.core.shard.lane_mesh`) shards the *lane* axis over D
     devices: operands are laid out with ``NamedSharding`` over the
@@ -672,6 +781,15 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
         raise RuntimeError(
             f"scheme {scheme.name!r} needs fp64 vectors: enable x64 first "
             "or use a TPU-tier scheme (tpu_v3, ...).")
+    if scheme.wide:
+        if (mesh is not None or backend != "pallas"
+                or layout not in ("auto", "ellpack")
+                or (engine == "vm" and not specialize)):
+            raise ValueError(
+                f"scheme {scheme.name!r} runs on backend='pallas', layout "
+                "'ellpack', the phases engine or the specialized VM, no "
+                "mesh")
+        layout = "ellpack"
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
@@ -701,9 +819,13 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
         with span("batch.wait", rid=rid):
             its = np.asarray(st.it)
         with span("batch.results", rid=rid):
-            return _results(bag, its, np.asarray(rrs_dev),
-                            np.asarray(st.status), xs, trace_dev,
-                            scheme=scheme, method=method,
+            if scheme.wide:
+                xs, rrs = dfloat.to_f64(xs), dfloat.to_f64(rrs_dev)
+                reps = np.asarray(st.rep.count)
+            else:
+                rrs, reps = np.asarray(rrs_dev), None
+            return _results(bag, its, rrs, np.asarray(st.status), xs,
+                            trace_dev, reps, scheme=scheme, method=method,
                             with_trace=with_trace, with_status=with_status)
 
 
@@ -810,21 +932,24 @@ def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
         raise ValueError(f"tol has {len(tol)} entries for {G_real} problems")
 
     vd = scheme.vector_dtype
+    vec, sol = (dfloat.DF, dfloat.TF) if scheme.wide else (vd, vd)
     with span("batch.put", rid=rid):
         mat = tuple(jnp.asarray(h) for h in host)
         if layout == "ellpack":
             mat = (mat[0], mat[1].astype(scheme.matrix_dtype), mat[2])
         # Padded rows get a unit diagonal and zero rhs: their residual is
         # identically zero, so they never influence rr or termination.
-        diag = _pad_stack([a.diagonal() for a in csrs], n_pad, 1.0, vd)
-        b = _pad_stack(bs, n_pad, 0.0, vd)
-        x0 = _pad_stack(x0s, n_pad, 0.0, vd)
+        diag = _pad_stack([a.diagonal() for a in csrs], n_pad, 1.0, vec)
+        b = _pad_stack(bs, n_pad, 0.0, vec)
+        x0 = _pad_stack(x0s, n_pad, 0.0, sol)
         if np.ndim(tol) == 0:
             tol_vec = jnp.full(G, float(tol), vd)
         else:
             tol_vec = jnp.asarray(
                 np.concatenate([np.asarray(tol, np.float64),
                                 np.ones(G - G_real)]), vd)
+        if scheme.wide:
+            tol_vec = tol_vec * (MARGIN * MARGIN)
     return _Bag(G=G, G_real=G_real, ns=ns, layout=layout, groups=groups,
                 n_col_tiles=n_col_tiles, bucket_dims=bucket_dims,
                 index_bytes=index_bytes, mat=mat, diag=diag, b=b, x0=x0,
@@ -880,9 +1005,10 @@ def _runner(bag: _Bag, *, engine, policy, program, specialize, scheme,
             (jnp.asarray(prog_np),) + operands, method + "|generic")
 
 
-def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, *, scheme,
+def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, reps, *, scheme,
              method, with_trace, with_status) -> List[CGResult]:
-    """Feed the call's counters and build one ``CGResult`` per lane."""
+    """Feed the call's counters and build one ``CGResult`` per lane;
+    ``reps`` are a wide scheme's replacements per lane, else None."""
     G, G_real, mat = bag.G, bag.G_real, bag.mat
     tols = np.asarray(bag.tol)
 
@@ -904,6 +1030,9 @@ def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, *, scheme,
     n_bd = int(sum(is_breakdown(int(c)) and np.isfinite(rrs[g])
                    for g, c in enumerate(statuses[:G_real])))
     spmv_events = G_real + int(its[:G_real].sum()) + n_bd
+    if reps is not None:
+        m.bump("replacements", int(reps[:G_real].sum()))
+        spmv_events += int(reps[:G_real].sum())
     m.bump("solves")
     m.bump("lanes", G_real)
     m.bump("iterations", int(its[:G_real].sum()))
@@ -918,5 +1047,6 @@ def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, *, scheme,
             x=xs[g, : bag.ns[g]], iterations=int(its[g]), rr=float(rrs[g]),
             converged=bool(rrs[g] <= tols[g]), residual_trace=trace,
             scheme=scheme.name, method=method,
-            status=status_name(int(statuses[g])) if with_status else None))
+            status=status_name(int(statuses[g])) if with_status else None,
+            replacements=0 if reps is None else int(reps[g])))
     return results

@@ -71,6 +71,8 @@ class CGResult:
     # True when the serving engine's escalation policy re-ran this
     # request at fp64 after a mixed-precision breakdown.
     retried: bool = False
+    # Residual replacements on the device (wide schemes, tpu_v3_df).
+    replacements: int = 0
 
     def __repr__(self) -> str:  # keep array printing out of logs
         extra = f", status={self.status}" if self.status else ""

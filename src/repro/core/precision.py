@@ -28,7 +28,15 @@ lower tier, which is the hardware-adaptation argument recorded in DESIGN.md.
   tpu_v1        BF16    BF16    BF16
   tpu_v2        BF16    BF16    FP32
   tpu_v3        BF16    FP32    FP32   <- Callipepla's choice, TPU tier
+  tpu_v3_df     BF16    2×FP32  2×FP32  vectors 2×FP32 (x 3×FP32)
   ============  ======  ======  ======
+
+``tpu_v3_df`` is Mix-V3 itself on this chip: the matrix narrow, the
+vectors wide.  A wide vector is a pair of fp32 words and x a triple
+(:mod:`repro.core.dfloat`), flagged ``wide``; the solver under it
+replaces its recurrence residual by ``b − A·x`` on the device and
+reports ``CONVERGED`` only on a replaced residual
+(:mod:`repro.core.batch`).  It runs on the Pallas ELLPACK path.
 """
 from __future__ import annotations
 
@@ -46,6 +54,8 @@ class PrecisionScheme:
     spmv_in_dtype: jnp.dtype   # x as consumed by the SpMV
     spmv_acc_dtype: jnp.dtype  # multiply/accumulate dtype inside the SpMV
     vector_dtype: jnp.dtype    # main-loop vectors (r, p, x, z, ap) and scalars
+    wide: bool = False         # vectors as fp32 pairs, x a triple (dfloat);
+                               # vector_bytes is then one word's
 
     @property
     def matrix_bytes(self) -> int:
@@ -85,6 +95,8 @@ SCHEMES = {
     "tpu_v1":   PrecisionScheme("tpu_v1",   _bf16, _bf16, _bf16, _f32),
     "tpu_v2":   PrecisionScheme("tpu_v2",   _bf16, _bf16, _f32, _f32),
     "tpu_v3":   PrecisionScheme("tpu_v3",   _bf16, _f32,  _f32, _f32),
+    "tpu_v3_df": PrecisionScheme("tpu_v3_df", _bf16, _f32, _f32, _f32,
+                                 wide=True),
 }
 
 

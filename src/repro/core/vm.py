@@ -59,13 +59,15 @@ the phase engine and the cache economics of each; the front doors are
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional, Tuple
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.batch import _cached, _matvec_factory, _row_dot, _run_chunked
+from repro.core.batch import (Replacement, _cached, _matvec_factory,
+                              _row_dot, _run_chunked, replace_residual)
+from repro.core.dfloat import DF, hi, is_wide, select
 from repro.core.compile import executable_key
 from repro.core.isa import (BUF, CTRL_ALPHA, ITYPE_COMP, ITYPE_CTRL,
                             ITYPE_VCTRL, SREG)
@@ -96,6 +98,7 @@ class BatchedVMState(NamedTuple):
     sregs: jax.Array     # [6, G] scalar registers (α β rz rr pap rz')
     active: jax.Array    # bool[G] live-lane mask
     trace: jax.Array     # [G, maxiter] rr per iteration, or [G, 0]
+    rep: Any = None      # Replacement under a wide scheme, else None
 
 
 def _masked_trace(trace, k, keep, rr_new):
@@ -183,13 +186,30 @@ def _make_executor(matvec):
 def vm_init(matvec, diag, b, x0, *, maxiter: int, with_trace: bool,
             tol, detect: bool = True) -> BatchedVMState:
     """Controller warm-up (paper Alg. 1 lines 1–5) — arithmetic identical
-    to :func:`repro.core.batch._batched_init`, packed into VM buffers."""
-    vd = b.dtype
+    to :func:`repro.core.batch._batched_init`, packed into VM buffers.
+
+    Wide operands (:mod:`repro.core.dfloat`) keep ``mem`` as a tuple of
+    per-buffer values: x has three fp32 words and the other buffers two,
+    so one stacked array would give every buffer a third word (half as
+    much again of memory and of every vector pass).  They carry no queue
+    file: the specialized path derives every queue within a tick, and
+    the generic VM, which would need wide queues, refuses the scheme."""
     G = b.shape[0]
     r = b - matvec(x0)
     z = r / diag
     rz = _row_dot(r, z)
     rr = _row_dot(r, r)
+    if is_wide(b):
+        sregs = DF.zeros((_N_SREGS, G))
+        sregs = sregs.at[SREG["rz"]].set(rz).at[SREG["rr"]].set(rr)
+        return BatchedVMState(
+            k=jnp.zeros((), jnp.int32), it=jnp.zeros(G, jnp.int32),
+            status=initial_status(rr.hi, tol, detect=detect),
+            mem=(x0, r, z, DF.zeros(r.shape), diag, b), queues=None,
+            sregs=sregs, active=rr.hi > tol,
+            trace=jnp.zeros((G, maxiter if with_trace else 0), jnp.float32),
+            rep=Replacement(rr.hi, jnp.zeros(G, jnp.int32)))
+    vd = b.dtype
     mem = jnp.stack([x0, r, z, jnp.zeros_like(r), diag, b])  # x r p ap M b
     sregs = jnp.zeros((_N_SREGS, G), vd)
     sregs = sregs.at[SREG["rz"]].set(rz).at[SREG["rr"]].set(rr)
@@ -407,6 +427,7 @@ class _SpecCarry(NamedTuple):
     sregs: jax.Array
     active: jax.Array
     trace: jax.Array
+    rep: Any = None
 
 
 def _spec_carry_of(st: BatchedVMState, plan: _ProgramPlan) -> _SpecCarry:
@@ -414,7 +435,7 @@ def _spec_carry_of(st: BatchedVMState, plan: _ProgramPlan) -> _SpecCarry:
         k=st.k, it=st.it, status=st.status,
         mem=tuple(st.mem[i] for i in plan.carried_bufs),
         queues=tuple(st.queues[q] for q in plan.live_queues),
-        sregs=st.sregs, active=st.active, trace=st.trace)
+        sregs=st.sregs, active=st.active, trace=st.trace, rep=st.rep)
 
 
 def _state_of_spec_carry(c: _SpecCarry, st0: BatchedVMState,
@@ -429,25 +450,32 @@ def _state_of_spec_carry(c: _SpecCarry, st0: BatchedVMState,
     pass-through contract (asserted by the serving-engine tests).
     """
     mem = st0.mem
-    for i, v in zip(plan.carried_bufs, c.mem):
-        mem = mem.at[i].set(v)
+    if isinstance(mem, tuple):               # wide: one value a buffer
+        done = dict(zip(plan.carried_bufs, c.mem))
+        mem = tuple(done.get(i, v) for i, v in enumerate(mem))
+    else:
+        for i, v in zip(plan.carried_bufs, c.mem):
+            mem = mem.at[i].set(v)
     queues = st0.queues
     for q, v in zip(plan.live_queues, c.queues):
         queues = queues.at[q].set(v)
     return BatchedVMState(k=c.k, it=c.it, status=c.status, mem=mem,
                           queues=queues, sregs=c.sregs, active=c.active,
-                          trace=c.trace)
+                          trace=c.trace, rep=c.rep)
 
 
 def _spec_body(plan: _ProgramPlan, matvec, tol, maxiter_vec=None, *,
-               bound=None, write_trace=True, detect=True):
+               bound=None, write_trace=True, detect=True, b=None):
     """Specialized VM tick — identical masking semantics to
     :func:`_vm_body`, applied per carried buffer/queue; ``bound`` makes
     the tick self-gating for chunked execution (see
     :func:`repro.core.batch._batched_body`); ``detect`` classifies the
     same candidate scalar registers through the same
     :func:`repro.core.metrics.tick_health`, so the two VM paths stay
-    guaranteed-identical with detection on or off."""
+    guaranteed-identical with detection on or off.  ``b`` (a wide
+    scheme's right-hand side) arms
+    :func:`repro.core.batch.replace_residual` on the tick's x, r, rz and
+    rr, as the phases engine does."""
     wb = frozenset(plan.written_bufs)
     wq = frozenset(plan.written_queues)
 
@@ -462,16 +490,25 @@ def _spec_body(plan: _ProgramPlan, matvec, tol, maxiter_vec=None, *,
         keep = c.active & go
         rr_cand = n_sregs[SREG["rr"]]
         upd, bd_i, bd_n = tick_health(
-            keep, n_sregs[SREG["pap"]], n_sregs[SREG["alpha"]],
-            n_sregs[SREG["beta"]], rr_cand, detect=detect)
+            keep, hi(n_sregs[SREG["pap"]]), hi(n_sregs[SREG["alpha"]]),
+            hi(n_sregs[SREG["beta"]]), hi(rr_cand), detect=detect)
+        rep = c.rep
+        if b is not None:
+            x, r, rz = n_mem[BUF["x"]], n_mem[BUF["r"]], n_sregs[SREG["rz"]]
+            r, rz, rr_cand, rep = replace_residual(
+                matvec, b, m_in[BUF["M"]], x, r, rz, rr_cand, rep, upd, tol)
+            n_mem = {**n_mem, BUF["r"]: r}
+            n_sregs = n_sregs.at[SREG["rz"]].set(rz).at[SREG["rr"]].set(
+                rr_cand)
+        rr_cand = hi(rr_cand)
         kv = upd[:, None]
-        mem = tuple(jnp.where(kv, n_mem[i], old) if i in wb else old
+        mem = tuple(select(kv, n_mem[i], old) if i in wb else old
                     for i, old in zip(plan.carried_bufs, c.mem))
         queues = tuple(jnp.where(kv, n_q[q], old) if q in wq else old
                        for q, old in zip(plan.live_queues, c.queues))
-        sregs = jnp.where(upd[None, :], n_sregs, c.sregs)
+        sregs = select(upd[None, :], n_sregs, c.sregs)
         it = c.it + upd.astype(jnp.int32)
-        rr = sregs[SREG["rr"]]
+        rr = hi(sregs[SREG["rr"]])
         if write_trace:
             trace = _masked_trace(c.trace, c.k, upd, rr_cand)
         else:
@@ -487,7 +524,7 @@ def _spec_body(plan: _ProgramPlan, matvec, tol, maxiter_vec=None, *,
         active = jnp.where(keep, live, c.active)
         return _SpecCarry(k=c.k + go.astype(jnp.int32), it=it,
                           status=status, mem=mem, queues=queues,
-                          sregs=sregs, active=active, trace=trace)
+                          sregs=sregs, active=active, trace=trace, rep=rep)
 
     return body
 
@@ -533,9 +570,12 @@ def make_vm_runner(*, backend, scheme, maxiter, with_trace, layout=None,
         block_rows=block_rows, col_tile=col_tile,
         n_col_tiles=n_col_tiles, interpret=interpret)
     hoist_trace = with_trace and steps_per_sync > 1
-    rr_of = lambda s: s.sregs[SREG["rr"]]  # noqa: E731
+    rr_of = lambda s: hi(s.sregs[SREG["rr"]])  # noqa: E731
 
     if program is None:
+        if scheme.wide:
+            raise ValueError(f"scheme {scheme.name!r} runs on the "
+                             "specialized VM only (pass program=)")
         def run(program, mat, diag, b, x0, tol):
             matvec = matvec_of(mat)
             st = vm_init(matvec, diag, b, x0, maxiter=maxiter,
@@ -566,13 +606,18 @@ def make_vm_runner(*, backend, scheme, maxiter, with_trace, layout=None,
         return run_sharded
 
     plan = _analyze_program(program)
+    if scheme.wide and plan.live_queues:
+        raise ValueError(f"scheme {scheme.name!r} carries no queue between "
+                         "ticks; this program reads queues it has not "
+                         f"written ({plan.live_queues})")
 
     def run_spec(mat, diag, b, x0, tol):
         matvec = matvec_of(mat)
         st0 = vm_init(matvec, diag, b, x0, maxiter=maxiter,
                       with_trace=with_trace, tol=tol, detect=detect)
         tick = _spec_body(plan, matvec, tol, bound=maxiter,
-                          write_trace=not hoist_trace, detect=detect)
+                          write_trace=not hoist_trace, detect=detect,
+                          b=b if scheme.wide else None)
 
         def cond(c):
             return (c.k < maxiter) & jnp.any(c.active)

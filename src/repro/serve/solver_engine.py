@@ -672,6 +672,10 @@ class SolverEngine:
 
     def _pool(self, scheme: Optional[str], policy: Optional[str]) -> _Pool:
         scheme = get_scheme(self.cfg.scheme if scheme is None else scheme)
+        if scheme.wide:
+            raise ValueError(f"scheme {scheme.name!r} replaces residuals in "
+                             "jpcg_solve_batched; the engine has no pool "
+                             "for it")
         policy = self.cfg.policy if policy is None else policy
         key = (scheme.name, policy)
         if key not in self._pools:

@@ -152,3 +152,46 @@ def test_vm_runner_xla(shape):
     assert _kernels(c) == 0
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+#: ecology2's operand (1000 x 1000 grounded grid, 999,999 rows) as two
+#: lanes stack it: 3907 row blocks -> 4096, 5 slabs, 3 slots -> 4, 1954
+#: x tiles -> 2048
+ECO_G, ECO_B, ECO_T, ECO_E, ECO_TILES = 2, 4096, 5, 4, 2048
+
+
+@pytest.mark.parametrize("words", [2, 3])
+def test_spmv_ellpack_df(shape, words):
+    """The double-fp32 kernel at ecology2's stacked dims, with x as a
+    pair (a search direction) and as a triple (the solution)."""
+    from repro.kernels.spmv_df import spmv_pallas_df_batched
+    blk = (ECO_G, ECO_B, ECO_T, ECO_E, smoke.BLOCK_ROWS)
+    nc = smoke.COL_TILE // 128
+    c = _compile(
+        jax.jit(lambda tc, v, lc, x: spmv_pallas_df_batched(
+            tc, v, lc, x, words=words)),
+        shape((ECO_G, ECO_B, ECO_T), jnp.int32),
+        shape(blk, jnp.bfloat16), shape(blk, jnp.int32),
+        shape((ECO_G, ECO_TILES, words * nc, 128), jnp.float32))
+    assert _kernels(c) == ECO_G      # one launch per system
+
+
+def test_vm_runner_df(shape):
+    """The specialized VM runner under ``tpu_v3_df`` at ecology2's dims:
+    a launch per lane for the recurrence's SpMV, the warm-up's and the
+    replacement's, and the whole solve fits one 16 GB chip."""
+    from repro.core.dfloat import DF, TF
+    run = make_vm_runner(
+        backend="pallas", scheme="tpu_v3_df", maxiter=20_000,
+        with_trace=False, layout="ellpack", block_rows=smoke.BLOCK_ROWS,
+        col_tile=smoke.COL_TILE, n_col_tiles=ECO_TILES, interpret=False,
+        program=np.asarray(canonical_program("paper"), np.int32))
+    n = ECO_B * smoke.BLOCK_ROWS
+    blk = (ECO_G, ECO_B, ECO_T, ECO_E, smoke.BLOCK_ROWS)
+    w = shape((ECO_G, n), jnp.float32)
+    c = _compile(run, (shape((ECO_G, ECO_B, ECO_T), jnp.int32),
+                       shape(blk, jnp.bfloat16), shape(blk, jnp.int32)),
+                 DF(w, w), DF(w, w), TF(w, w, w), shape((ECO_G,), jnp.float32))
+    assert _kernels(c) == 3 * ECO_G
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

@@ -188,3 +188,26 @@ def test_escalated_request_keeps_its_first_admission():
     (req,) = [s for s in spans if s.name == "engine.request"]
     assert req.rid == rid and req.start_ns == admits[0].start_ns
     assert req.end_ns > admits[1].end_ns
+
+
+@pytest.mark.parametrize("scheme", ["tpu_v3_df", "tpu_v3"])
+def test_replacement_scope_only_under_the_wide_scheme(scheme):
+    """The replacement's device work is named ``vm_replace`` in the op
+    metadata, and its SpMV ``spmv_ellpack_df``: both only where a wide
+    scheme replaces residuals."""
+    from repro.core.batch import _prepare, _runner
+    from repro.core.precision import get_scheme
+    sch = get_scheme(scheme)
+    bag = _prepare([poisson_2d(6)], None, None, 1e-10, scheme=sch,
+                   backend="pallas", layout="ellpack", bucket=True,
+                   block_rows=256, col_tile=512, mesh=None, rid=0)
+    _, make, args, _ = _runner(
+        bag, engine="vm", policy=None, program=None, specialize=True,
+        scheme=sch, backend="pallas", maxiter=50, with_trace=False,
+        block_rows=256, col_tile=512, steps_per_sync=8, donate=False,
+        detect=True, interpret=True, mesh=None)
+    text = make().lower(*args).compile().as_text()
+    wide = scheme == "tpu_v3_df"
+    assert ("/vm_replace/" in text) == wide
+    assert ("spmv_ellpack_df" in text) == wide
+    assert "/vm_dot/" in text
