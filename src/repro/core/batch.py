@@ -732,8 +732,11 @@ def jpcg_solve_batched(problems: Sequence, bs: Optional[Sequence] = None,
     backend, ``"ellpack"`` / ``"sell"`` on Pallas.  Values are packed at
     ``scheme.matrix_dtype`` and indices at int16/int32 by ``n_pad`` at
     stacking time; the layout and index width join the executable cache
-    key.  Every layout is bit-identical to every other for the same
-    scheme (shared :func:`tree_sum` reduction bracketing).
+    key.  On the Pallas ELLPACK path lanes that hold one operator (the
+    same ``CSRMatrix``, or bit-equal ones) share one pack, which the
+    device copies to each of them.  Every layout is bit-identical to
+    every other for the same scheme (shared :func:`tree_sum` reduction
+    bracketing).
 
     ``detect`` (default True; static, joins the cache key) arms in-loop
     breakdown detection: a lane whose tick produces ``pAp ≤ 0`` or a
@@ -835,6 +838,7 @@ class _Bag(NamedTuple):
 
     G: int                  # lanes, shard padding included
     G_real: int             # lanes of the caller's problems
+    operands: int           # operands packed on the host for the G lanes
     ns: List[int]           # each lane's rows
     layout: str
     groups: Optional[tuple]
@@ -848,9 +852,53 @@ class _Bag(NamedTuple):
     tol: jax.Array
 
 
-def _pack(csrs, *, backend, layout, scheme, bucket, block_rows, col_tile):
-    """Stack the lanes' matrices on the host in ``layout``: returns the
-    stacked operand, its host arrays and its static shape signature."""
+def _same_operator(a: CSRMatrix, b: CSRMatrix) -> bool:
+    """Whether two lanes hold the same operator: the same object, or
+    equal shape and nnz and bit-equal ``indptr``, ``indices`` and
+    ``data``."""
+    def bits(v):
+        return np.ascontiguousarray(v).view(np.uint8)
+
+    return a is b or (
+        a.shape == b.shape and a.nnz == b.nnz
+        and np.array_equal(a.indptr, b.indptr)
+        and np.array_equal(a.indices, b.indices)
+        and a.data.dtype == b.data.dtype
+        and np.array_equal(bits(a.data), bits(b.data)))
+
+
+def _distinct_operators(csrs):
+    """``(ops, lane_to_op)``: the lanes' distinct operators in order of
+    first appearance, and the index into ``ops`` of each lane's own."""
+    ops, lane_to_op = [], []
+    for a in csrs:
+        k = next((k for k, o in enumerate(ops) if _same_operator(a, o)),
+                 len(ops))
+        if k == len(ops):
+            ops.append(a)
+        lane_to_op.append(k)
+    return ops, tuple(lane_to_op)
+
+
+def _at_matrix_dtype(a: CSRMatrix, scheme: PrecisionScheme) -> CSRMatrix:
+    """``a`` with its values rounded on the host as a put and a device
+    cast round them: to the dtype ``jnp.asarray`` gives them (float32
+    unless x64 is on), then to the scheme's matrix dtype, each step to
+    nearest even."""
+    put = jax.dtypes.canonicalize_dtype(a.data.dtype)
+    mat = jax.dtypes.canonicalize_dtype(scheme.matrix_dtype)
+    return CSRMatrix(a.indptr, a.indices,
+                     a.data.astype(put, copy=False).astype(mat, copy=False),
+                     a.shape)
+
+
+def _pack(csrs, ops, *, backend, layout, scheme, bucket, block_rows,
+          col_tile):
+    """Stack the matrices on the host in ``layout``: returns the stacked
+    operand, its host arrays and its static shape signature.  SELL and
+    row-ELL stack every lane of ``csrs``; ELLPACK stacks each of the
+    lanes' distinct operators ``ops`` once, at the scheme's matrix
+    dtype, and the put copies them to their lanes on the device."""
     groups = n_col_tiles = None
     if layout == "sell":
         stacked = stack_sell(csrs, bucket=bucket, scheme=scheme)
@@ -867,8 +915,9 @@ def _pack(csrs, *, backend, layout, scheme, bucket, block_rows, col_tile):
         index_bytes = stacked.index_bytes
     elif backend == "pallas" and layout == "ellpack":
         stacked = stack_ellpack(
-            [csr_to_ellpack(a, block_rows=block_rows, col_tile=col_tile)
-             for a in csrs], bucket=bucket)
+            [csr_to_ellpack(_at_matrix_dtype(a, scheme),
+                            block_rows=block_rows, col_tile=col_tile)
+             for a in ops], bucket=bucket)
         host = (stacked.tile_cols, stacked.vals, stacked.local_cols)
         n_col_tiles = stacked.n_col_tiles
         bucket_dims = stacked.vals.shape[1:]
@@ -881,9 +930,10 @@ def _pack(csrs, *, backend, layout, scheme, bucket, block_rows, col_tile):
 
 def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
              block_rows, col_tile, mesh, rid) -> Optional[_Bag]:
-    """Choose the layout, pack the lanes and put every operand on the
-    device (spans ``batch.layout``, ``batch.pack``, ``batch.put``);
-    ``None`` for an empty bag."""
+    """Choose the layout, pack the lanes (ELLPACK: each distinct
+    operator once) and put every operand on the device (spans
+    ``batch.layout``, ``batch.pack``, ``batch.put``); ``None`` for an
+    empty bag."""
     csrs = [_as_csr(a) for a in problems]
     G = len(csrs)
     if G == 0:
@@ -906,12 +956,14 @@ def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
         if G != G_real:
             csrs = csrs + [_as_csr(np.eye(1))] * (G - G_real)
     with span("batch.pack", rid=rid):
+        ops, lane_to_op = (_distinct_operators(csrs) if layout == "ellpack"
+                           else (csrs, tuple(range(G))))
         stacked, host, groups, n_col_tiles, bucket_dims, index_bytes = \
-            _pack(csrs, backend=backend, layout=layout, scheme=scheme,
+            _pack(csrs, ops, backend=backend, layout=layout, scheme=scheme,
                   bucket=bucket, block_rows=block_rows, col_tile=col_tile)
 
     n_pad = stacked.padded_rows
-    ns = [s[0] for s in stacked.shapes]
+    ns = [a.shape[0] for a in csrs]
     bs = list(bs) if bs is not None else [np.ones(n) for n in ns[:G_real]]
     x0s = (list(x0s) if x0s is not None
            else [np.zeros(n) for n in ns[:G_real]])
@@ -935,11 +987,14 @@ def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
     vec, sol = (dfloat.DF, dfloat.TF) if scheme.wide else (vd, vd)
     with span("batch.put", rid=rid):
         mat = tuple(jnp.asarray(h) for h in host)
-        if layout == "ellpack":
-            mat = (mat[0], mat[1].astype(scheme.matrix_dtype), mat[2])
+        if len(ops) < G:
+            # the lanes' operands, copied from the distinct ones on device
+            idx = jnp.asarray(lane_to_op, jnp.int32)
+            mat = tuple(jnp.take(m, idx, axis=0, mode="clip") for m in mat)
         # Padded rows get a unit diagonal and zero rhs: their residual is
         # identically zero, so they never influence rr or termination.
-        diag = _pad_stack([a.diagonal() for a in csrs], n_pad, 1.0, vec)
+        diags = [a.diagonal() for a in ops]
+        diag = _pad_stack([diags[k] for k in lane_to_op], n_pad, 1.0, vec)
         b = _pad_stack(bs, n_pad, 0.0, vec)
         x0 = _pad_stack(x0s, n_pad, 0.0, sol)
         if np.ndim(tol) == 0:
@@ -950,7 +1005,8 @@ def _prepare(problems, bs, x0s, tol, *, scheme, backend, layout, bucket,
                                 np.ones(G - G_real)]), vd)
         if scheme.wide:
             tol_vec = tol_vec * (MARGIN * MARGIN)
-    return _Bag(G=G, G_real=G_real, ns=ns, layout=layout, groups=groups,
+    return _Bag(G=G, G_real=G_real, operands=len(ops), ns=ns,
+                layout=layout, groups=groups,
                 n_col_tiles=n_col_tiles, bucket_dims=bucket_dims,
                 index_bytes=index_bytes, mat=mat, diag=diag, b=b, x0=x0,
                 tol=tol_vec)
@@ -1035,6 +1091,7 @@ def _results(bag: _Bag, its, rrs, statuses, xs, trace_dev, reps, *, scheme,
         spmv_events += int(reps[:G_real].sum())
     m.bump("solves")
     m.bump("lanes", G_real)
+    m.bump("operands_packed", bag.operands)
     m.bump("iterations", int(its[:G_real].sum()))
     m.bump("spmv_calls", spmv_events)
     m.bump("bytes_streamed_est", spmv_events * int(lane_stream_bytes))

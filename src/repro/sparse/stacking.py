@@ -237,11 +237,23 @@ def stack_ellpack(mats: Sequence[EllpackMatrix], *,
     T = fine(max(m.n_slabs for m in mats))
     E = rnd(max(m.ell for m in mats))
     n_tiles = fine(max(m.n_col_tiles for m in mats))
-    padded = [pad_ellpack(m, n_row_blocks=B, n_slabs=T, ell=E) for m in mats]
+
+    # each lane is written once, into the zeroed stack at its final shape
+    def zeros(shape, name):
+        return np.zeros((len(mats), *shape),
+                        np.result_type(*(getattr(m, name).dtype
+                                         for m in mats)))
+
+    tile_cols = zeros((B, T), "tile_cols")
+    vals = zeros((B, T, E, r), "vals")
+    local_cols = zeros((B, T, E, r), "local_cols")
+    for g, m in enumerate(mats):
+        b, t, e = m.n_row_blocks, m.n_slabs, m.ell
+        tile_cols[g, :b, :t] = m.tile_cols
+        vals[g, :b, :t, :e] = m.vals
+        local_cols[g, :b, :t, :e] = m.local_cols
     return StackedEllpack(
-        tile_cols=np.stack([m.tile_cols for m in padded]),
-        vals=np.stack([m.vals for m in padded]),
-        local_cols=np.stack([m.local_cols for m in padded]),
+        tile_cols=tile_cols, vals=vals, local_cols=local_cols,
         shapes=tuple(m.shape for m in mats),
         nnzs=tuple(m.nnz for m in mats),
         block_rows=r, col_tile=c, n_col_tiles=n_tiles)

@@ -1,14 +1,16 @@
 """Batched multi-system JPCG: lane-vs-single parity, on-the-fly per-lane
 termination, bucket compile-cache reuse, SolverEngine admission."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.batch import (batch_cache_clear, batch_cache_info,
                               jpcg_solve_batched)
 from repro.core.cg import jpcg_solve
-from repro.sparse import (csr_to_dense, diag_dominant_spd, poisson_2d,
-                          random_spd, tridiagonal_spd)
+from repro.core.precision import get_scheme
+from repro.sparse import (CSRMatrix, csr_to_dense, diag_dominant_spd,
+                          poisson_2d, random_spd, tridiagonal_spd)
 from repro.sparse.stacking import bucket_up
 from repro.serve.solver_engine import SolverEngine, SolverEngineConfig
 from oracles import (assert_lane_equal, assert_results_bit_identical,
@@ -149,6 +151,131 @@ class TestBucketCache:
         exact = jpcg_solve_batched(probs, bucket=False, **kw)
         assert all(r.status == "CONVERGED" for r in exact)
         assert_results_bit_identical(fine, exact, status=True, rr=True)
+
+
+def _near_bf16_ties(a):
+    """``a`` with every value moved to just above a bf16 rounding tie,
+    by less than a bf16 ulp: float64 -> float32 -> bf16 rounds it to
+    even, where one rounding from float64 would round it up."""
+    v = np.abs(a.data)
+    ulp = 2.0 ** (np.floor(np.log2(v)) - 7)
+    tie = (np.floor(v / ulp) + 0.5) * ulp
+    data = np.sign(a.data) * tie * (1 + 2.0 ** -40)
+    return CSRMatrix(a.indptr, a.indices, data, a.shape)
+
+
+def _operator(seed, n=300):
+    return _near_bf16_ties(diag_dominant_spd(n, nnz_per_row=6,
+                                             dominance=1.2, seed=seed))
+
+
+def _copy(a):
+    return CSRMatrix(a.indptr.copy(), a.indices.copy(), a.data.copy(),
+                     a.shape)
+
+
+def _bag(name):
+    """Bags of lanes that share operators, by object or by value, and
+    one whose two operators differ in one value's last bit."""
+    a, b = _operator(3), _operator(4, n=260)
+    a1 = _copy(a)
+    a1.data[5] = np.nextafter(a1.data[5], np.inf)
+    return {"AA": [a, a], "ABA": [a, b, a], "A-copyA": [a, _copy(a)],
+            "A-A1": [a, a1]}[name]
+
+
+def _per_lane_operand(lanes, scheme, bucket):
+    """The ELLPACK operand as one pack per lane gives it: each lane
+    packed, padded and stacked in float64, put, then cast on the
+    device."""
+    from repro.sparse.ellpack import csr_to_ellpack
+    from repro.sparse.stacking import fine_bucket_up, pad_ellpack
+    ells = [csr_to_ellpack(a, **BK) for a in lanes]
+    fine = fine_bucket_up if bucket else int
+    B = fine(max(m.n_row_blocks for m in ells))
+    T = fine(max(m.n_slabs for m in ells))
+    E = (bucket_up if bucket else int)(max(m.ell for m in ells))
+    padded = [pad_ellpack(m, n_row_blocks=B, n_slabs=T, ell=E)
+              for m in ells]
+    tc, v, lc = (jnp.asarray(np.stack([getattr(m, k) for m in padded]))
+                 for k in ("tile_cols", "vals", "local_cols"))
+    return tc, v.astype(get_scheme(scheme).matrix_dtype), lc
+
+
+def _prepare_ellpack(lanes, scheme, bucket):
+    from repro.core.batch import _prepare
+    return _prepare(lanes, None, None, 1e-10, scheme=get_scheme(scheme),
+                    backend="pallas", layout="ellpack", bucket=bucket,
+                    mesh=None, rid=0, **BK)
+
+
+class TestSharedOperator:
+    """A bag's lanes that hold one operator share one pack: the device
+    operand, and so every result, is bit-identical to packing each
+    lane."""
+
+    @pytest.mark.parametrize("x64", [True, False])
+    @pytest.mark.parametrize("bucket", [True, False])
+    @pytest.mark.parametrize("name,operands", [
+        ("AA", 1), ("ABA", 2), ("A-copyA", 1), ("A-A1", 2)])
+    def test_operand_bytes_match_per_lane_pack(self, name, operands,
+                                               bucket, x64):
+        lanes = _bag(name)
+        with jax.enable_x64(x64):
+            bag = _prepare_ellpack(lanes, "tpu_v3", bucket)
+            ref = _per_lane_operand(lanes, "tpu_v3", bucket)
+            assert bag.operands == operands
+            for got, want in zip(bag.mat, ref):
+                got, want = np.asarray(got), np.asarray(want)
+                assert got.dtype == want.dtype
+                assert got.shape == want.shape
+                np.testing.assert_array_equal(got.view(np.uint8),
+                                              want.view(np.uint8))
+
+    @pytest.mark.parametrize("scheme", ["tpu_v3", "tpu_v3_df"])
+    @pytest.mark.parametrize("name", ["AA", "A-copyA"])
+    def test_solves_match_per_lane_pack(self, name, scheme, monkeypatch):
+        import repro.core.batch as batch
+        lanes = _bag(name)
+        kw = dict(tol=1e-10, maxiter=400, scheme=scheme, backend="pallas",
+                  layout="ellpack", **BK)
+        got = jpcg_solve_batched(lanes, **kw)
+        prepare = batch._prepare
+
+        def per_lane(*args, **kwargs):
+            bag = prepare(*args, **kwargs)
+            return bag._replace(mat=_per_lane_operand(
+                lanes, scheme, kwargs["bucket"]))
+
+        monkeypatch.setattr(batch, "_prepare", per_lane)
+        ref = jpcg_solve_batched(lanes, **kw)
+        assert all(r.status == "CONVERGED" for r in ref)
+        assert_results_bit_identical(got, ref, rr=True, status=True)
+
+    @pytest.mark.parametrize("name,operands", [("AA", 1), ("ABA", 2)])
+    def test_operands_packed_counter(self, name, operands):
+        from repro.core.metrics import solver_metrics
+        m = solver_metrics()
+        before = m.get("operands_packed")
+        jpcg_solve_batched(_bag(name), tol=1e-10, maxiter=5,
+                           scheme="tpu_v3", backend="pallas",
+                           layout="ellpack", **BK)
+        assert m.get("operands_packed") - before == operands
+
+    def test_no_operand_kept_across_calls(self):
+        """A call packs what its lanes hold now: a second call after the
+        operator's values changed in place solves the new operator."""
+        a = _operator(5)
+        kw = dict(tol=1e-10, maxiter=400, scheme="tpu_v3",
+                  backend="pallas", layout="ellpack", **BK)
+        first = jpcg_solve_batched([a, a], **kw)
+        a.data[:] *= 2.0
+        second = jpcg_solve_batched([a, a], **kw)
+        fresh = _copy(a)
+        ref = jpcg_solve_batched([fresh, fresh], **kw)
+        assert_results_bit_identical(second, ref, rr=True, status=True)
+        assert not np.array_equal(np.asarray(first[0].x),
+                                  np.asarray(second[0].x))
 
 
 class TestSolverEngine:

@@ -130,8 +130,54 @@ class TestShardedBitIdentity:
             m = solver_metrics().snapshot()
             assert m["lanes"] == len(probs)
             assert sum(m["exit_status"].values()) == len(probs)
+            # Pallas ELLPACK packs each distinct operator once; the
+            # identity padding lanes share one more
+            a, b = random_spd(16, cond=50.0, seed=1), tridiagonal_spd(24)
+            kw = dict(tol=1e-10, maxiter=50, scheme="tpu_v3",
+                      backend="pallas", layout="ellpack", **BK)
+            reset_solver_metrics()
+            got = jpcg_solve_batched([a, a, b], mesh=mesh, **kw)
+            padded = pad_lanes(3, mesh) > 3
+            assert solver_metrics().get("operands_packed") == 2 + padded
+            ref = jpcg_solve_batched([a, a, b], **kw)
+            assert_results_bit_identical(got, ref, rr=True, status=True)
         finally:
             reset_solver_metrics()
+
+    def test_identity_padding_is_one_more_operator(self):
+        """On two devices a Pallas ELLPACK bag [A, A, B] pads to four
+        lanes: A, B and the identity padding are its three distinct
+        operators, each packed once, and every observable is
+        bit-identical to the same sharded solve with one pack per lane.
+        (Against the unsharded solve this path is not bit-identical on
+        every host's XLA:CPU, with or without shared packing.)"""
+        out = _run("""
+            import repro.core.batch as batch
+            from repro.core.metrics import solver_metrics
+            from repro.core.shard import lane_mesh
+            from repro.sparse import random_spd, tridiagonal_spd
+            a, b = random_spd(16, cond=50.0, seed=1), tridiagonal_spd(24)
+            kw = dict(tol=1e-10, maxiter=50, scheme="tpu_v3",
+                      backend="pallas", layout="ellpack", block_rows=8,
+                      col_tile=128, mesh=lane_mesh())
+            m = solver_metrics()
+            got = batch.jpcg_solve_batched([a, a, b], **kw)
+            shared = m.get("operands_packed")
+            batch._distinct_operators = lambda csrs: (
+                list(csrs), tuple(range(len(csrs))))
+            ref = batch.jpcg_solve_batched([a, a, b], **kw)
+            print(json.dumps({
+                "devices": jax.device_count(),
+                "operands": [shared, m.get("operands_packed") - shared],
+                "statuses": [r.status for r in got],
+                "same": all(lanes_equal(r, o) for r, o in zip(got, ref)),
+                "lanes": len(got)}))
+        """, devices=2, prelude=_BAG_SRC)
+        assert out["devices"] == 2
+        assert out["operands"] == [3, 4]
+        assert out["lanes"] == 3
+        assert out["statuses"] == ["CONVERGED"] * 3
+        assert out["same"]
 
     def test_sharded_engine_matches_unsharded(self):
         """A sharded SolverEngine serving mixed-fate requests harvests
